@@ -54,7 +54,11 @@ def main() -> None:
     from benchmarks import (bench_kernels, bench_step, fig34_trends,
                             roofline_table, table1_characteristics,
                             table3_perf_model, table45_roofline)
-    from repro.analysis.hw import V5E
+    import jax
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    dev = jax.devices()[0]
 
     modules = [
         ("table1", table1_characteristics),
@@ -102,7 +106,8 @@ def main() -> None:
         payload = {
             "schema": 1,
             "git_rev": _git_rev(),
-            "chip": V5E.name,
+            "device": {"platform": dev.platform, "kind": dev.device_kind,
+                       "count": len(jax.devices())},
             "tuned_plans": os.environ.get("REPRO_BENCH_TUNED") == "1",
             "smoke": os.environ.get("REPRO_BENCH_SMOKE") == "1",
             "backend": os.environ.get("REPRO_BENCH_BACKEND") or "default",

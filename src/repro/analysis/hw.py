@@ -32,6 +32,21 @@ class TpuChip:
 
 V5E = TpuChip()
 
+#: Chips by ``jax.Device.device_kind``.  A TPU whose kind is missing here
+#: is an error (:func:`chip_for_kind`), never a silent V5E default.
+CHIPS = {"TPU v5 lite": V5E}
+
+
+def chip_for_kind(device_kind: str) -> TpuChip:
+    """The :class:`TpuChip` of a TPU device kind; raises for unknown kinds."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no TpuChip entry for device kind {device_kind!r} (known: "
+            f"{sorted(CHIPS)}); add its constants to "
+            f"repro.analysis.hw.CHIPS") from None
+
 
 @dataclasses.dataclass(frozen=True)
 class PaperDevice:

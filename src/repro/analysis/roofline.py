@@ -520,11 +520,8 @@ def parse_collectives(hlo_text: str) -> Dict[str, float]:
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """Normalize ``compiled.cost_analysis()`` across JAX versions.
-
-    Older JAX returns a one-element list of dicts (per device assignment);
-    newer JAX returns the dict directly.
-    """
+    """``compiled.cost_analysis()`` as one dict (a list of per-device
+    dicts keeps its first entry)."""
     ca = compiled.cost_analysis()
     if isinstance(ca, (list, tuple)):
         ca = ca[0] if ca else {}

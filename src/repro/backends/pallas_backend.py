@@ -1,9 +1,8 @@
 """Pallas backends: the temporal-blocked superstep kernels behind the registry.
 
-Version 1 targets the post-rename Pallas API through the compat shim in
-``kernels/common.py`` (``MemorySpace`` vs ``TPUMemorySpace`` resolved at
-import); a future API break becomes a ``version=2`` registration rather than
-an edit-in-place, so old lowerings remain addressable.
+Version 1 targets the installed Pallas API (``pltpu.MemorySpace``); a
+future API break becomes a ``version=2`` registration rather than an
+edit-in-place, so old lowerings remain addressable.
 
 The ``-pipelined`` siblings select the double-buffered prefetch kernel
 (``kernels/common.build_pipelined_kernel``) — the TPU analogue of the

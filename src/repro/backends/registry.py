@@ -4,9 +4,8 @@ The frontend (``StencilProgram``) describes *what* to compute; a backend
 decides *how*.  This mirrors the layered lowering the paper's toolchain
 implies (OpenCL source -> AOC -> bitstream) and that Stencil-HMLS makes
 explicit (DSL -> MLIR dialects -> target): the IR stays fixed while backends
-evolve independently — and carry a version so API-drift shims (e.g. the
-Pallas ``MemorySpace`` rename) can be introduced as new versions without
-deleting the old lowering.
+evolve independently — and carry a version so a changed lowering can be
+introduced as a new version without deleting the old one.
 
 Built-in backends (registered in ``repro.backends``):
 

@@ -9,7 +9,7 @@ Layers:
   perf_model — the paper's FPGA performance model, reproduced for validation
   temporal   — superstep driver built on the Pallas kernels
   distributed— shard_map domain decomposition + deep-halo exchange
-  compat     — JAX API-drift shims (mesh / shard_map)
+  compat     — Auto-axis mesh, shard_map and tracer helpers
 
 Backends (``repro.backends``) lower a program+plan to an executable.
 """

@@ -51,6 +51,43 @@ VARIANTS = ("plain", "pipelined", "temporal")
 TEMPORAL_CHUNK = 4
 
 
+def tile_alignment(ndim: int, compiled: bool,
+                   dtype: str = "float32") -> Tuple[int, ...]:
+    """Per-axis alignment of a compiled kernel's HBM DMA windows.
+
+    Mosaic tiles the last two axes of an HBM array by the register tile
+    (second minor by ``SUBLANE`` rows per 32-bit word, minor by ``LANE``)
+    and refuses any DMA slice whose start or extent is not a tile multiple.
+    Leading axes are untiled.  The interpreter has no tiling, so interpret
+    mode keeps every axis at alignment 1 (the exact halo geometry).
+    """
+    if not compiled:
+        return (1,) * ndim
+    itemsize = 4 if dtype == "float32" else 2
+    return (1,) * (ndim - 2) + (SUBLANE * (4 // itemsize), LANE)
+
+
+def guard_rows(halo_radius: int) -> int:
+    """Rows of slack above and below a VMEM frame: the strip compute's
+    sublane taps read up to ``halo_radius`` rows past the frame, and a
+    guard of whole row tiles keeps every compiled strip load aligned."""
+    return round_up(halo_radius, SUBLANE)
+
+
+def frame_buffer_shape(block_shape: Tuple[int, ...], ring: int,
+                       align: Tuple[int, ...],
+                       halo_radius: int) -> Tuple[int, ...]:
+    """Shape of one VMEM frame buffer of the padded-carry kernel.
+
+    The frame is the block plus the ring on both sides, the ring rounded
+    up to ``align`` per axis so the frame DMA stays tile-aligned; the
+    second-minor axis also carries :func:`guard_rows` on both sides.
+    """
+    shape = [b + 2 * round_up(ring, a) for b, a in zip(block_shape, align)]
+    shape[-2] += 2 * guard_rows(halo_radius)
+    return tuple(shape)
+
+
 def normalize_variant(variant=None, pipelined: bool = False) -> str:
     """One rule for the ``pipelined: bool`` -> ``variant: str`` migration.
 
@@ -105,30 +142,29 @@ class BlockPlan:
         padded = math.prod(self.padded_shape)
         return 2 * padded * itemsize
 
-    def vmem_bytes_for(self, variant="plain") -> int:
-        """Variant-aware VMEM footprint of the superstep kernel's scratch.
+    def vmem_bytes_for(self, variant="plain", compiled: bool = True) -> int:
+        """Variant-aware VMEM scratch of the compiled padded-carry kernel.
 
-        The ``-pipelined`` double-buffered kernel revolves two halo'd window
-        buffers (prefetch g+1 while g computes); the plain kernel holds just
-        one.  The ``-temporal`` kernel holds one *chunk-deep* window —
-        ``block + 2 * TEMPORAL_CHUNK * halo`` per axis — because a single
-        launch fuses ``TEMPORAL_CHUNK`` supersteps (eq. 2 with
-        ``par_time * TEMPORAL_CHUNK`` fused steps).  All variants stage the
-        output tile through a block-shaped buffer.  ``vmem_bytes`` (always
-        2 windows) is the historical conservative bound; pruning plain-kernel
-        plans with it forfeits bigger blocks / deeper ``par_time`` for no
-        reason.  ``variant`` also accepts the legacy bool.
+        Every variant steps the fused time steps between two frame buffers
+        (the DMA target and a work frame); the ``-pipelined`` kernel adds a
+        second DMA target so block g+1 streams in while g computes.  The
+        ``-temporal`` frames carry the *chunk-deep* ring
+        (``TEMPORAL_CHUNK * halo``), because one launch fuses
+        ``TEMPORAL_CHUNK`` supersteps.  Frames carry guard rows and, for
+        ``compiled`` kernels, a ring rounded to the register tile
+        (:func:`frame_buffer_shape`); the interpreter keeps the exact ring.
+        ``vmem_bytes`` (two bare windows) is the historical bound.
+        ``variant`` also accepts the legacy bool.
         """
+        prog = self.program
         itemsize = 4 if self.spec.dtype == "float32" else 2
         v = normalize_variant(variant)
-        if v == "temporal":
-            window = math.prod(b + 2 * TEMPORAL_CHUNK * self.halo
-                               for b in self.block_shape)
-            windows = 1
-        else:
-            window = math.prod(self.padded_shape)
-            windows = 2 if v == "pipelined" else 1
-        return itemsize * (windows * window + math.prod(self.block_shape))
+        ring = self.halo * (TEMPORAL_CHUNK if v == "temporal" else 1)
+        align = tile_alignment(prog.ndim, compiled, self.spec.dtype)
+        frame = math.prod(frame_buffer_shape(self.block_shape, ring, align,
+                                             prog.halo_radius))
+        frames = 3 if v == "pipelined" else 2
+        return itemsize * frames * frame
 
     # ---- redundancy accounting (paper's overlapped blocking cost) ----------
 

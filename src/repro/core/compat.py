@@ -1,53 +1,38 @@
-"""JAX API-drift shims (mesh/shard_map level).
+"""Small helpers over the installed JAX's mesh, shard_map and trace APIs.
 
-The repo targets a range of JAX versions; the distributed stack touches
-several APIs that moved between releases:
-
-* ``jax.sharding.AxisType`` + ``jax.make_mesh(..., axis_types=...)`` —
-  newer JAX only; older versions build the same (fully ``Auto``) mesh
-  without the kwarg.
-* ``jax.shard_map`` — top-level since 0.6 (with ``check_vma``); older
-  versions expose ``jax.experimental.shard_map.shard_map`` (with
-  ``check_rep``).
-
-Pallas-specific drift (``MemorySpace`` vs ``TPUMemorySpace``) is resolved in
-``repro.kernels.common`` next to the kernels that consume it.
+* :func:`make_mesh` builds a fully ``Auto`` mesh (``jax.make_mesh``
+  defaults to ``Explicit`` axes, which the sharded executor does not use).
+* :func:`shard_map` is ``jax.shard_map`` without the varying-manual-axes
+  check (the stencil kernels are opaque Pallas calls).
+* :func:`tracing` tells host-side instrumentation that it runs inside a
+  jax trace.
 """
 
 from __future__ import annotations
 
 import jax
 
-AxisType = getattr(jax.sharding, "AxisType", None)
 
-
-def tracing() -> bool:
-    """True while jax is tracing.
+def tracing(x) -> bool:
+    """True when ``x``, the array argument of an instrumented call, is a
+    jax tracer.
 
     Host-side instrumentation (the ``repro.obs`` flight recorder, which is
     deliberately jax-free) must not time, block, or emit per-run events
     inside a trace — a jitted wrapper around an instrumented entry point
     would otherwise record trace-time garbage once per compile.
     """
-    try:
-        return not jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - jax internals drift
-        return False
+    return isinstance(x, jax.core.Tracer)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with all-Auto axis types where supported."""
-    if AxisType is not None:
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices,
-                             axis_types=(AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(axis_shapes, axis_names, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` without replication checking, on any JAX version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` without replication checking."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -297,7 +297,7 @@ class DistributedStencil:
         self.variant = traits.variant
         self.pipelined = traits.variant == "pipelined"
         if self.interpret is None:
-            self.interpret = traits.interpret or common.default_interpret()
+            self.interpret = traits.interpret
 
         for d in range(self.program.ndim):
             n = self.decomp.shards(self.mesh, d)
@@ -393,8 +393,11 @@ class DistributedStencil:
         wrap_axes = tuple(
             d for d in range(ndim)
             if periodic and not (decomp.partition[d] and shards[d] > 1))
-        layout = common.PaddedLayout(halo=H, local_shape=local,
-                                     rounded=local, wrap_axes=wrap_axes)
+        layout = common.PaddedLayout(
+            halo=H, local_shape=local, rounded=local, wrap_axes=wrap_axes,
+            align=common.tile_alignment(ndim, not self.interpret,
+                                        program.dtype))
+        ring = layout.ring
         interpret, variant = self.interpret, self.variant
         global_shape = tuple(self.global_shape)
         rem_plan = dataclasses.replace(plan, par_time=rem) if rem else None
@@ -408,7 +411,7 @@ class DistributedStencil:
             offs = jnp.stack([jnp.asarray(o, jnp.int32) for o in offsets])
             # Pad ONCE into ring layout; every superstep refreshes only the
             # h-deep strips over ICI and ping-pongs the padded pair.
-            src = jnp.pad(grid, [(0, 0)] * nb + [(H, H)] * ndim)
+            src = jnp.pad(grid, [(0, 0)] * nb + [(g, g) for g in ring])
             dst = jnp.zeros_like(src)
 
             def superstep(carry, step_plan):
@@ -417,23 +420,31 @@ class DistributedStencil:
                 for dd in range(ndim):
                     axes = decomp.partition[dd]
                     if axes and shards[dd] > 1:
-                        s = _exchange_into_ring(s, nb + dd, axes, h, H,
-                                                local[dd], periodic,
-                                                shards[dd])
+                        s = _exchange_into_ring(s, nb + dd, axes, h,
+                                                ring[dd], local[dd],
+                                                periodic, shards[dd])
                 s2, o = common._padded_superstep_pallas(
                     s, d2, center, taps, program=program, plan=step_plan,
                     layout=layout, global_shape=global_shape,
                     interpret=interpret, offsets=offs, variant=variant)
                 return (o, s2)
 
-            carry = lax.fori_loop(0, full,
-                                  lambda _, c: superstep(c, plan),
-                                  (src, dst))
-            if rem_plan is not None:
-                carry = superstep(carry, rem_plan)
             interior = (slice(None),) * nb + tuple(
-                slice(H, H + local[d]) for d in range(ndim))
-            return carry[0][interior]
+                slice(ring[d], ring[d] + local[d]) for d in range(ndim))
+
+            def finish(carry):
+                if rem_plan is not None:
+                    carry = superstep(carry, rem_plan)
+                return carry[0][interior]
+
+            # two supersteps per trip keep the pair in its loop slots (as
+            # in common.run_call: a swapping body copies both buffers)
+            carry = lax.fori_loop(
+                0, full // 2,
+                lambda _, c: superstep(superstep(c, plan), plan), (src, dst))
+            return lax.cond(full % 2 == 1,
+                            lambda c: finish(superstep(c, plan)), finish,
+                            carry)
 
         mapped = compat.shard_map(
             local_body, mesh=self.mesh,
@@ -472,7 +483,7 @@ class DistributedStencil:
             return grid
         full, rem = divmod(steps, self.plan.par_time)
         rec = obs.active()
-        if rec is not None and not compat.tracing():
+        if rec is not None and not compat.tracing(grid):
             # Tag what each superstep's ICI exchange moves: the full
             # supersteps refresh a plan.halo-deep ring per sharded axis,
             # the remainder superstep a shallower rem*halo_radius one.

@@ -62,7 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.analysis.hw import TpuChip, V5E
+from repro.analysis.hw import TpuChip, V5E, chip_for_kind
 from repro.backends import lower, resolve_backend
 from repro.core import compat
 from repro.core.blocking import BlockPlan, plan_blocking
@@ -85,10 +85,12 @@ from repro.tuning.space import (Candidate, MeshDecomposition,
 Devices = Union[None, int, Tuple[int, ...]]
 
 
-#: obs must not time or block under a jax trace — a jitted wrapper around
-#: ``CompiledStencil.run`` would otherwise record trace-time garbage and
-#: try to block on tracers.
-_tracing = compat.tracing
+def local_chip() -> TpuChip:
+    """The planner's target: the attached TPU, looked up by device kind
+    (an unknown kind raises), or V5E on a host without a TPU."""
+    if jax.default_backend() != "tpu":
+        return V5E
+    return chip_for_kind(jax.devices()[0].device_kind)
 
 
 def _as_int(value) -> Optional[int]:
@@ -177,7 +179,7 @@ class Stencil:
                 pipelined: Optional[bool] = None,
                 donate: bool = True,
                 interpret: Optional[bool] = None,
-                hw: TpuChip = V5E,
+                hw: Optional[TpuChip] = None,
                 max_par_time: int = 32,
                 cache: bool = True,
                 cache_path: Optional[str] = None,
@@ -198,7 +200,7 @@ class Stencil:
                       interpret=interpret, hw=hw, max_par_time=max_par_time,
                       cache=cache, cache_path=cache_path, sanitize=sanitize)
         rec = obs.active()
-        if rec is None or _tracing():
+        if rec is None:
             return self._compile(grid_shape, **kwargs)
         plan_source = plan if isinstance(plan, str) else "pinned"
         before = common.trace_counts()
@@ -230,7 +232,7 @@ class Stencil:
                  variant: Optional[str] = None,
                  donate: bool = True,
                  interpret: Optional[bool] = None,
-                 hw: TpuChip = V5E,
+                 hw: Optional[TpuChip] = None,
                  max_par_time: int = 32,
                  cache: bool = True,
                  cache_path: Optional[str] = None,
@@ -271,6 +273,9 @@ class Stencil:
                      the caller's grid is never consumed either way.
         interpret    force the Pallas interpreter on/off (None = follow the
                      backend's traits / platform auto-detection).
+        hw           the chip the planner targets (None = the local TPU's
+                     entry in ``analysis.hw.CHIPS``, V5E on a host without
+                     one).
         sanitize     also run the RP4xx canary sanitizer (interpret-mode
                      execution with NaN-poisoned halos, ``repro.lint.
                      sanitize``) before accepting the compile — slow but
@@ -281,6 +286,8 @@ class Stencil:
                      are covered by the symbolic half).
         """
         prog = self.program
+        if hw is None:
+            hw = local_chip()
         try:
             # operator.index: accept ints/np ints, reject silently-truncating
             # floats — a (128.5, 512) grid must fail HERE, not at run()
@@ -397,6 +404,13 @@ class Stencil:
         # with stable RP codes; warnings survive on CompiledStencil.preflight
         preflight = _preflight(prog, resolved, grid_shape, hw,
                                decomp=decomp_axes, variant=traits.variant)
+        if interpret is None and traits.fused_run:
+            # pin the backend's declared mode BEFORE any executor is built
+            # (the mesh executor would otherwise auto-resolve None): a
+            # compiled backend (pallas-tpu, interpret=False) must FAIL on
+            # a host that cannot compile it — exactly like its registry
+            # lowering — not silently fall back to the interpreter
+            interpret = traits.interpret
         sanitize_report = None
         if traits.fused_run:
             # RP4xx: prove the padded ring schedule itself (wrap/exchange
@@ -405,7 +419,8 @@ class Stencil:
             # sanitizer is the opt-in dynamic oracle on top.
             preflight.extend(raise_on_error(
                 verify_dataflow(prog, resolved, grid_shape, steps=steps,
-                                variant=traits.variant, decomp=decomp_axes),
+                                variant=traits.variant, decomp=decomp_axes,
+                                compiled=not interpret),
                 source="dataflow"))
             if sanitize and decomp_axes is None:
                 sanitize_report = sanitize_run(
@@ -419,14 +434,6 @@ class Stencil:
             variant=traits.variant,
             decomp=MeshDecomposition(decomp_axes) if decomp_axes else None)
         cost = predict(prog, cand, hw, grid_shape=grid_shape)
-
-        if interpret is None and traits.fused_run:
-            # pin the backend's declared mode BEFORE any executor is built
-            # (the mesh executor would otherwise auto-resolve None): a
-            # compiled backend (pallas-tpu, interpret=False) must FAIL on
-            # a host that cannot compile it — exactly like its registry
-            # lowering — not silently fall back to the interpreter
-            interpret = traits.interpret
 
         dist = None
         lowered = None
@@ -645,7 +652,9 @@ class CompiledStencil:
         grid = jnp.asarray(grid)
         self._check_grid(grid)
         rec = obs.active()
-        if rec is None or _tracing():
+        # obs must not time or block under a jax trace: a jitted wrapper
+        # around run() would record trace-time garbage and block on tracers
+        if rec is None or compat.tracing(grid):
             return self._dispatch(grid, steps)
         return self._run_recorded(rec, grid, steps)
 

@@ -22,9 +22,13 @@ TPU-native design (see DESIGN.md §2 for the FPGA -> TPU map):
 The kernel bodies are generated from a :class:`StencilProgram` tap set —
 star/box/diamond all lower through the same emitter (codegen.py).
 
-Pallas API drift shim: ``pltpu.MemorySpace`` (new) vs ``pltpu.TPUMemorySpace``
-(old) are resolved at import time; both expose the same ANY/VMEM/SMEM members
-and scratch constructors, so the kernels run on either JAX generation.
+The padded-carry kernels (the fused executor's path) are written for the
+TPU compiler: every HBM DMA window is register-tile aligned (the carry ring
+is rounded up to the tile on compiled backends, :func:`tile_alignment`),
+the coefficients are SMEM scalars, and the fused steps run as row-strip
+loops over VMEM frame buffers (sublane taps are offset loads, lane taps
+lane rotations) instead of whole-block array values, whose unrolled code
+grows with the block area.
 """
 
 from __future__ import annotations
@@ -43,16 +47,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.blocking import (  # noqa: F401 (re-export)
-    BlockPlan, TEMPORAL_CHUNK, normalize_variant, round_up)
+    SUBLANE, BlockPlan, TEMPORAL_CHUNK, guard_rows, normalize_variant,
+    round_up, tile_alignment)
 from repro.core.codegen import boundary_pad, tap_interior_update
 from repro.core.program import ProgramCoeffs, StencilProgram
 
-# ---- Pallas API drift shim -------------------------------------------------
-# jax >= 0.5 renamed ``TPUMemorySpace`` to ``MemorySpace`` (and kept the
-# enum members).  Resolve once; everything below uses the resolved name.
-
-MemorySpace = getattr(pltpu, "MemorySpace", None) \
-    or getattr(pltpu, "TPUMemorySpace")
+MemorySpace = pltpu.MemorySpace
 
 #: VMEM scratch constructor — ``vmem_scratch(shape, dtype)``.
 vmem_scratch = pltpu.VMEM
@@ -431,28 +431,40 @@ class PaddedLayout:
     leave the ring stale and instead heal each *loaded window* with a t=0
     ``boundary_fixup`` — the border cell is always inside the window, so the
     fixup reproduces ``boundary_pad`` bit-for-bit at O(window-surface) cost.
+
+    ``align`` is the per-axis DMA alignment (:func:`tile_alignment`; empty
+    means 1 everywhere): the ring actually allocated on axis d, ``ring[d]``,
+    is ``halo`` rounded up to it, so every window and tile a compiled
+    kernel moves starts on a register tile.
     """
 
     halo: int
     local_shape: Tuple[int, ...]
     rounded: Tuple[int, ...]
     wrap_axes: Tuple[int, ...] = ()
+    align: Tuple[int, ...] = ()
+
+    @property
+    def ring(self) -> Tuple[int, ...]:
+        align = self.align or (1,) * len(self.rounded)
+        return tuple(round_up(self.halo, a) for a in align)
 
     @property
     def padded_shape(self) -> Tuple[int, ...]:
-        return tuple(r + 2 * self.halo for r in self.rounded)
+        return tuple(r + 2 * g for r, g in zip(self.rounded, self.ring))
 
     def wrap_degenerate(self) -> bool:
         """True when some wrap axis is too small for the in-kernel refresh.
 
-        The lo ring copies ``halo`` cells out of the true interior and the
-        hi region (round-up slack + hi ring) copies ``rounded - n + halo``
+        The lo ring copies ``ring`` cells out of the true interior and the
+        hi region (round-up slack + hi ring) copies ``rounded - n + ring``
         cells; either exceeding the axis extent ``n`` would need multi-lap
         wrap copies, so such configs fall back to the legacy re-pad path.
         """
+        ring = self.ring
         for d in self.wrap_axes:
             n = self.local_shape[d]
-            if self.halo > n or self.rounded[d] - n + self.halo > n:
+            if ring[d] > n or self.rounded[d] - n + ring[d] > n:
                 return True
         return False
 
@@ -496,12 +508,12 @@ def wrap_copies(layout: PaddedLayout) -> Tuple[RingCopy, ...]:
     ``jnp.pad`` wrap corner semantics): the lo ring ``[0, H)`` copies from
     the last ``H`` true cells ``[n, n+H)`` and the hi region ``[H+n, P)``
     (round-up slack plus hi ring, width ``W = P - H - n``) copies from the
-    first ``W`` true cells ``[H, H+W)``.
+    first ``W`` true cells ``[H, H+W)``, with ``H = layout.ring[d]``.
     """
-    H = layout.halo
     P = layout.padded_shape
     copies = []
     for d in layout.wrap_axes:
+        H = layout.ring[d]
         n = layout.local_shape[d]
         W = P[d] - H - n
         copies.append(RingCopy("wrap", d, (n, n + H), (0, H)))
@@ -551,7 +563,9 @@ class SuperstepSchedule:
     the alias map*: the buffer backing the tile output per
     :func:`ping_pong_aliases` — so a mis-aliased pair shows up here as
     ``write_buffer == read_buffer`` (the RP404 hazard).  ``window_offset``
-    is the ring offset ``H - h`` every block window reads at;
+    is the per-axis ring offset ``ring[d] - h`` at which the cells every
+    block's fused steps depend on start (the kernel may DMA a wider,
+    tile-aligned frame around them);
     ``ring_deferred`` marks a (buggy) schedule whose ring copies land
     after the dependent window reads.
     """
@@ -562,7 +576,7 @@ class SuperstepSchedule:
     variant: str
     read_buffer: int
     write_buffer: int
-    window_offset: int
+    window_offset: Tuple[int, ...]
     window_shape: Tuple[int, ...]
     write_tile: Tuple[int, ...]
     write_stride: Tuple[int, ...]
@@ -600,7 +614,7 @@ class RunSchedule:
 def ring_schedule(program: StencilProgram, plan: BlockPlan,
                   true_shape: Tuple[int, ...], steps: int, *,
                   variant: Optional[str] = None, pipelined: bool = False,
-                  decomp=None) -> RunSchedule:
+                  decomp=None, compiled: bool = False) -> RunSchedule:
     """Build the :class:`RunSchedule` that ``run_call`` (or the sharded
     ``run_fn``) executes for this configuration.
 
@@ -608,12 +622,14 @@ def ring_schedule(program: StencilProgram, plan: BlockPlan,
     ``variant="temporal"``, per-device local/rounded shapes under a
     ``decomp`` (axis shard counts or a ``MeshDecomposition``), wrap axes =
     device-local periodic axes, remainder supersteps as one shallower
-    plain superstep reading at ring offset ``H - h``.
+    plain superstep reading at ring offset ``ring - h``, and — for
+    ``compiled`` kernels — the ring rounded up to the register tile.
     """
     v = normalize_variant(variant, pipelined)
     ndim = program.ndim
     chunk = TEMPORAL_CHUNK if v == "temporal" else 1
     H = chunk * plan.halo
+    align = tile_alignment(ndim, compiled, program.dtype)
     shards = getattr(decomp, "axis_shards", decomp)
     if shards is not None:
         local = tuple(true_shape[d] // shards[d] for d in range(ndim))
@@ -630,7 +646,8 @@ def ring_schedule(program: StencilProgram, plan: BlockPlan,
             if program.boundary == "periodic" else ()
         sharded_axes = ()
     layout = PaddedLayout(halo=H, local_shape=local, rounded=rounded,
-                          wrap_axes=wrap_axes)
+                          wrap_axes=wrap_axes, align=align)
+    ring = layout.ring
     if shards is None and layout.wrap_degenerate():
         return RunSchedule(program=program, plan=plan, layout=layout,
                            variant=v, steps=steps, full=0, rem=0,
@@ -648,17 +665,18 @@ def ring_schedule(program: StencilProgram, plan: BlockPlan,
 
     def entry(index, rb, ss_steps, ss_variant):
         h = ss_steps * program.halo_radius
-        ring = wraps + tuple(
+        copies = wraps + tuple(
             c for d in sharded_axes
-            for c in exchange_copies(d, h, H, local[d]))
+            for c in exchange_copies(d, h, ring[d], local[d]))
         wb = rb if winput == 3 else 1 - rb
         return SuperstepSchedule(
             index=index, steps=ss_steps, halo=h, variant=ss_variant,
-            read_buffer=rb, write_buffer=wb, window_offset=H - h,
+            read_buffer=rb, write_buffer=wb,
+            window_offset=tuple(g - h for g in ring),
             window_shape=tuple(b + 2 * h for b in plan.block_shape),
             write_tile=tuple(plan.block_shape),
             write_stride=tuple(plan.block_shape),
-            ring=ring, fixup=program.boundary != "periodic",
+            ring=copies, fixup=program.boundary != "periodic",
             aliases=tuple(sorted(amap.items())))
 
     supersteps = []
@@ -704,35 +722,264 @@ def _refresh_wrap_halo(src_ref, layout: PaddedLayout, batch: Optional[int],
         cp.wait()
 
 
+#: Bytes of VMEM a padded-carry launch may use beyond its frame buffers
+#: (strip values, rotated lanes, Mosaic's internal scratch).  The scoped
+#: limit passed to the compiler is the frames plus this; the planner's
+#: budget (``TpuChip.vmem_budget_bytes``) keeps the sum under the chip.
+VMEM_HEADROOM_BYTES = 16 * 1024**2
+
+
+@dataclasses.dataclass(frozen=True)
+class _Frame:
+    """One launch's VMEM working frame.
+
+    ``shape`` is the block plus the (tile-rounded) ring on both sides of
+    every axis — the exact HBM window the launch DMAs in.  In its buffer
+    the frame starts ``guard`` rows down the second-minor axis
+    (:func:`repro.core.blocking.frame_buffer_shape`); batched launches keep
+    a leading unit axis, addressed by ``lead``.
+    """
+
+    shape: Tuple[int, ...]
+    guard: int
+    batched: bool
+
+    @property
+    def lead(self) -> Tuple[int, ...]:
+        return (0,) if self.batched else ()
+
+    @property
+    def strip(self) -> int:
+        return min(SUBLANE, self.shape[-2])
+
+    def buffer_shape(self) -> Tuple[int, ...]:
+        s = list(self.shape)
+        s[-2] += 2 * self.guard
+        return ((1,) if self.batched else ()) + tuple(s)
+
+    def rows(self, plane, r0, pad: int = 0):
+        """Load/store index of row strip ``r0`` of a plane, widened by
+        ``pad`` rows on both sides (``pad`` is 0 or ``guard``, so a strip
+        starting on a tile row stays tile-aligned)."""
+        return self.lead + tuple(plane) + (
+            pl.ds(self.guard + r0 - pad, self.strip + 2 * pad), slice(None))
+
+    def region(self, starts, sizes):
+        """DMA view of a box of the frame (frame coordinates)."""
+        idx = [pl.ds(s, n) for s, n in zip(starts, sizes)]
+        idx[-2] = pl.ds(self.guard + starts[-2], sizes[-2])
+        return ((pl.ds(0, 1),) if self.batched else ()) + tuple(idx)
+
+
+def _for_each_strip(frame: _Frame, body, planes=None) -> None:
+    """Call ``body(plane, r0)`` for every row strip of every plane.
+
+    ``plane`` is ``()`` for 2D frames and ``(z,)`` for 3D ones, whose
+    leading axis loops over ``planes`` (default: all).  Compiled frames
+    have a strip-multiple row count, so every ``r0`` is a tile row; other
+    (interpret-mode) frames end with an overlapping strip, which recomputes
+    rows it already holds.
+    """
+    rows = frame.shape[-2]
+    S = frame.strip
+    nstrips = -(-rows // S)
+    aligned = rows % S == 0
+
+    def strips(plane):
+        def one(i, carry):
+            r0 = pl.multiple_of(i * S, S) if aligned \
+                else jnp.minimum(i * S, rows - S)
+            body(plane, r0)
+            return carry
+        lax.fori_loop(0, nstrips, one, 0)
+
+    if len(frame.shape) == 2:
+        strips(())
+        return
+    lo, hi = planes if planes is not None else (0, frame.shape[0])
+
+    def plane_body(z, carry):
+        strips((z,))
+        return carry
+    lax.fori_loop(lo, hi, plane_body, 0)
+
+
+def _apply_step(program: StencilProgram, center, taps, src, dst,
+                frame: _Frame) -> None:
+    """One stencil application over the whole frame, ``src`` -> ``dst``.
+
+    Same arithmetic as :func:`tap_interior_update` (canonical tap order,
+    no reassociation), computed one row strip at a time.  Per plane offset
+    the strip is loaded once — widened by the guard rows when some tap
+    moves along the second-minor axis, whose offsets are then static
+    sublane slices — and a lane offset is a lane rotation.  Cells within
+    ``halo_radius`` of the frame edge receive garbage (guard rows,
+    rotated-in lanes, unwritten planes); the overlapped blocking invariant
+    ``ring >= steps * halo_radius`` keeps it out of the block.
+    """
+    r = program.halo_radius
+    S, lanes, g = frame.strip, frame.shape[-1], frame.guard
+    zero = (0,) * program.ndim
+    row_keys = {off[:-2] for off in program.neighbor_taps if off[-2]}
+
+    def body(plane, r0):
+        loads = {}
+
+        def tap(off):
+            key = off[:-2]
+            if key not in loads:
+                at = tuple(p + o for p, o in zip(plane, key))
+                pad = g if key in row_keys else 0
+                loads[key] = (pad, src[frame.rows(at, r0, pad)])
+            pad, x = loads[key]
+            if pad:
+                x = x[pad + off[-2]:pad + off[-2] + S]
+            return x if off[-1] == 0 else pltpu.roll(x, (-off[-1]) % lanes, 1)
+
+        acc = center * tap(zero)
+        for k, off in enumerate(program.neighbor_taps):
+            acc = acc + taps[k] * tap(off)
+        dst[frame.rows(plane, r0)] = acc
+
+    _for_each_strip(frame, body,
+                    planes=(r, frame.shape[0] - r) if program.ndim == 3
+                    else None)
+
+
+def _fixup_frame(program: StencilProgram, buf, frame: _Frame, starts,
+                 true_shape: Tuple[int, ...]) -> None:
+    """Restore boundary semantics on the frame's out-of-grid cells.
+
+    The in-VMEM form of :func:`boundary_fixup`, in one strip pass and only
+    for launches whose frame crosses the grid boundary.  ``starts[d]`` is
+    the global coordinate of frame cell 0 on axis d.  Constant fills every
+    out-of-grid cell.  Clamp loads each strip from its clamped plane,
+    replaces out-of-grid rows by the border rows of that plane and then
+    out-of-grid lanes by each row's border lane (reduced out of the strip),
+    so corners take the border value of every axis.  Only out-of-grid
+    cells are written and only in-grid cells are read, so the in-place
+    pass is order-independent.
+    """
+    if program.boundary == "periodic":
+        return
+    nd = program.ndim
+    shape = frame.shape
+    S, lanes = frame.strip, shape[-1]
+    crosses = (starts[0] < 0) | (starts[0] + shape[0] > true_shape[0])
+    for d in range(1, nd):
+        crosses = crosses | (starts[d] < 0) \
+            | (starts[d] + shape[d] > true_shape[d])
+
+    def border(d):
+        n = true_shape[d]
+        return (jnp.clip(-starts[d], 0, shape[d] - 1),
+                jnp.clip(n - 1 - starts[d], 0, shape[d] - 1))
+
+    def body(plane, r0):
+        rows, cols = nd - 2, nd - 1
+        rp = starts[rows] + r0 + lax.broadcasted_iota(
+            jnp.int32, (S, lanes), 0)
+        lp = starts[cols] + lax.broadcasted_iota(jnp.int32, (S, lanes), 1)
+        if program.boundary == "constant":
+            x = buf[frame.rows(plane, r0)]
+            out = (rp < 0) | (rp > true_shape[rows] - 1) \
+                | (lp < 0) | (lp > true_shape[cols] - 1)
+            if plane:
+                zp = starts[0] + plane[0]
+                out = out | (zp < 0) | (zp > true_shape[0] - 1)
+            buf[frame.rows(plane, r0)] = jnp.where(
+                out, jnp.asarray(program.boundary_value, x.dtype), x)
+            return
+        src = (jnp.clip(plane[0], *border(0)),) if plane else ()
+        x = buf[frame.rows(src, r0)]
+        first, last = border(rows)
+        lo = buf[frame.lead + src + (pl.ds(frame.guard + first, 1),
+                                     slice(None))]
+        hi = buf[frame.lead + src + (pl.ds(frame.guard + last, 1),
+                                     slice(None))]
+        x = jnp.where(rp < 0, lo, x)
+        x = jnp.where(rp > true_shape[rows] - 1, hi, x)
+        zero = jnp.zeros_like(x)
+        lo = jnp.sum(jnp.where(lp == 0, x, zero), axis=1, keepdims=True)
+        hi = jnp.sum(jnp.where(lp == true_shape[cols] - 1, x, zero), axis=1,
+                     keepdims=True)
+        x = jnp.where(lp < 0, lo, x)
+        buf[frame.rows(plane, r0)] = jnp.where(
+            lp > true_shape[cols] - 1, hi, x)
+
+    @pl.when(crosses)
+    def _fix():
+        _for_each_strip(frame, body)
+
+
+def _fused_steps_vmem(program: StencilProgram, steps: int, center, taps,
+                      buf, work, frame: _Frame, starts,
+                      true_shape: Tuple[int, ...]):
+    """Heal the loaded frame, then run ``steps`` stencil applications
+    ping-ponging between ``buf`` and ``work``; returns the ref holding the
+    result (boundary semantics restored between steps, as in
+    :func:`_fused_steps`)."""
+    _fixup_frame(program, buf, frame, starts, true_shape)
+    cur, nxt = buf, work
+    for t in range(1, steps + 1):
+        _apply_step(program, center, taps, cur, nxt, frame)
+        cur, nxt = nxt, cur
+        if t < steps:
+            _fixup_frame(program, cur, frame, starts, true_shape)
+    return cur
+
+
+def _coeff_scalars(c_ref, t_ref, ntaps: int, dtype):
+    """The stencil coefficients, read out of SMEM as scalars."""
+    return (c_ref[0].astype(dtype),
+            [t_ref[k].astype(dtype) for k in range(ntaps)])
+
+
+def _launch_geometry(program: StencilProgram, plan: BlockPlan,
+                     layout: PaddedLayout, batch: Optional[int]):
+    """(frame, ring) of one padded-carry launch."""
+    ring = layout.ring
+    align = layout.align or (1,) * len(ring)
+    if any(b % a for b, a in zip(plan.block_shape, align)):
+        raise ValueError(
+            f"block {plan.block_shape} is not a multiple of the register "
+            f"tile {align} that compiled kernels DMA by; plan with the "
+            f"compiled backend (plan='auto' or 'model') or round the block")
+    shape = tuple(b + 2 * g for b, g in zip(plan.block_shape, ring))
+    return _Frame(shape, guard_rows(program.halo_radius),
+                  batch is not None), ring
+
+
 def build_padded_superstep_kernel(program: StencilProgram, plan: BlockPlan,
                                   layout: PaddedLayout,
                                   global_shape: Tuple[int, ...],
                                   batch: Optional[int] = None):
     """Kernel body for one superstep over the persistent padded carry.
 
-    Reads the halo'd input window straight out of the padded source buffer
-    (at ring offset ``layout.halo - plan.halo``, so a shallower remainder
-    superstep reuses the same ring), heals the stale boundary halo with a
-    t=0 ``boundary_fixup``, runs the fused steps, and DMAs the output tile
-    into the destination buffer's interior.  With ``layout.wrap_axes`` the
+    DMAs the block's frame (block + ring per axis, tile-aligned on compiled
+    backends) out of the padded source buffer, heals the stale boundary
+    ring with a t=0 fixup, runs the fused steps between two VMEM frames,
+    and DMAs the block interior into the destination buffer.  A shallower
+    remainder superstep reuses the same frame: its steps only need the
+    inner ``plan.halo`` cells of the ring.  With ``layout.wrap_axes`` the
     first grid iteration refreshes the periodic ring in place first — the
     source buffer is then also an aliased output (see
     ``_padded_superstep_pallas``).
     """
     ndim = program.ndim
     block = plan.block_shape
-    pb = plan.padded_shape
-    h = plan.halo
-    H = layout.halo
-    off = H - h
+    frame, ring = _launch_geometry(program, plan, layout, batch)
     wrap = bool(layout.wrap_axes)
+    ntaps = program.num_neighbor_taps
 
-    def _body(offs_ref, c_ref, t_ref, src_ref, o_ref, buf_ref, out_buf,
-              sem_in, sem_out, sem_wrap):
+    def _body(offs_ref, c_ref, t_ref, src_ref, o_ref, buf, work, sem_in,
+              sem_out, sem_wrap):
         if batch is None:
             pids = [pl.program_id(d) for d in range(ndim)]
+            hb = ()
         else:
             pids = [pl.program_id(d + 1) for d in range(ndim)]
+            hb = (pl.ds(pl.program_id(0), 1),)
         if wrap:
             first = pids[0] == 0
             for d in range(1, ndim):
@@ -744,40 +991,37 @@ def build_padded_superstep_kernel(program: StencilProgram, plan: BlockPlan,
             def _wrap():
                 _refresh_wrap_halo(src_ref, layout, batch, sem_wrap)
 
-        win_in = tuple(pl.ds(pids[d] * block[d] + off, pb[d])
-                       for d in range(ndim))
-        win_out = tuple(pl.ds(H + pids[d] * block[d], block[d])
-                        for d in range(ndim))
-        if batch is not None:
-            win_in = (pl.ds(pl.program_id(0), 1),) + win_in
-            win_out = (pl.ds(pl.program_id(0), 1),) + win_out
-        cp = pltpu.make_async_copy(src_ref.at[win_in], buf_ref, sem_in)
+        win = hb + tuple(pl.ds(pids[d] * block[d], frame.shape[d])
+                         for d in range(ndim))
+        cp = pltpu.make_async_copy(
+            src_ref.at[win], buf.at[frame.region((0,) * ndim, frame.shape)],
+            sem_in)
         cp.start()
         cp.wait()
 
-        coeffs = ProgramCoeffs(center=c_ref[0, 0], taps=t_ref[...][0])
-        cur = buf_ref[...] if batch is None else buf_ref[0]
-        starts0 = tuple(offs_ref[d] + pids[d] * block[d] - h
-                        for d in range(ndim))
-        cur = boundary_fixup(program, cur, starts0, global_shape)
-        res = _fused_steps(program, plan, coeffs, cur, pids, offs_ref,
-                           global_shape)
-        out_buf[...] = res if batch is None else res[jnp.newaxis]
-        cpo = pltpu.make_async_copy(out_buf, o_ref.at[win_out], sem_out)
+        center, taps = _coeff_scalars(c_ref, t_ref, ntaps, buf.dtype)
+        starts = tuple(offs_ref[d] + pids[d] * block[d] - ring[d]
+                       for d in range(ndim))
+        res = _fused_steps_vmem(program, plan.par_time, center, taps, buf,
+                                work, frame, starts, global_shape)
+        win_out = hb + tuple(pl.ds(ring[d] + pids[d] * block[d], block[d])
+                             for d in range(ndim))
+        cpo = pltpu.make_async_copy(res.at[frame.region(ring, block)],
+                                    o_ref.at[win_out], sem_out)
         cpo.start()
         cpo.wait()
 
     if wrap:
         def kernel(offs_ref, c_ref, t_ref, src_in, dst_in, src_ref, o_ref,
-                   buf_ref, out_buf, sem_in, sem_out, sem_wrap):
+                   buf, work, sem_in, sem_out, sem_wrap):
             del src_in, dst_in
-            _body(offs_ref, c_ref, t_ref, src_ref, o_ref, buf_ref, out_buf,
+            _body(offs_ref, c_ref, t_ref, src_ref, o_ref, buf, work,
                   sem_in, sem_out, sem_wrap)
     else:
-        def kernel(offs_ref, c_ref, t_ref, src_ref, dst_in, o_ref, buf_ref,
-                   out_buf, sem_in, sem_out):
+        def kernel(offs_ref, c_ref, t_ref, src_ref, dst_in, o_ref, buf,
+                   work, sem_in, sem_out):
             del dst_in
-            _body(offs_ref, c_ref, t_ref, src_ref, o_ref, buf_ref, out_buf,
+            _body(offs_ref, c_ref, t_ref, src_ref, o_ref, buf, work,
                   sem_in, sem_out, None)
     return kernel
 
@@ -790,21 +1034,18 @@ def build_padded_pipelined_kernel(program: StencilProgram, plan: BlockPlan,
     """Double-buffered padded-carry variant of the superstep kernel.
 
     Same prefetch schedule as :func:`build_pipelined_kernel` (block g+1's
-    DMA issued before block g's compute, buffers alternating by linearized
-    parity), lifted onto the persistent padded carry: windows read at ring
-    offset ``layout.halo - plan.halo``, a t=0 ``boundary_fixup`` heals the
-    stale ring per window, and the output tile is staged through a VMEM
-    scratch then DMA'd into the destination interior.  The periodic wrap
-    refresh runs once, before the very first prefetch, so every streamed
-    window already sees a fresh ring.
+    frame DMA issued before block g's compute, frame buffers alternating
+    by linearized parity), lifted onto the persistent padded carry: the
+    fused steps ping-pong between the current frame and a shared work
+    frame, and the block interior is DMA'd into the destination.  The
+    periodic wrap refresh runs once, before the very first prefetch, so
+    every streamed frame already sees a fresh ring.
     """
     ndim = program.ndim
     block = plan.block_shape
-    pb = plan.padded_shape
-    h = plan.halo
-    H = layout.halo
-    off = H - h
+    frame, ring = _launch_geometry(program, plan, layout, batch)
     wrap = bool(layout.wrap_axes)
+    ntaps = program.num_neighbor_taps
     vgrid = grid if batch is None else (batch,) + tuple(grid)
     nd_all = len(vgrid)
     total = math.prod(vgrid)
@@ -817,7 +1058,7 @@ def build_padded_pipelined_kernel(program: StencilProgram, plan: BlockPlan,
             rem = rem // vgrid[d]
         return tuple(reversed(idx))
 
-    def _body(offs_ref, c_ref, t_ref, src_ref, o_ref, buf0, buf1, out_buf,
+    def _body(offs_ref, c_ref, t_ref, src_ref, o_ref, buf0, buf1, work,
               sem0, sem1, sem_out, sem_wrap):
         ids = [pl.program_id(d) for d in range(nd_all)]
         lin = ids[0]
@@ -825,6 +1066,7 @@ def build_padded_pipelined_kernel(program: StencilProgram, plan: BlockPlan,
             lin = lin * vgrid[d] + ids[d]
         parity = jax.lax.rem(lin, 2)
         pids = ids if batch is None else ids[1:]
+        hb = () if batch is None else (pl.ds(ids[0], 1),)
 
         if wrap:
             @pl.when(lin == 0)
@@ -834,11 +1076,13 @@ def build_padded_pipelined_kernel(program: StencilProgram, plan: BlockPlan,
         def _copy(lin_idx, buf, sem):
             coords = _coords(lin_idx)
             sp = coords if batch is None else coords[1:]
-            window = tuple(pl.ds(sp[d] * block[d] + off, pb[d])
-                           for d in range(ndim))
+            win = tuple(pl.ds(sp[d] * block[d], frame.shape[d])
+                        for d in range(ndim))
             if batch is not None:
-                window = (pl.ds(coords[0], 1),) + window
-            return pltpu.make_async_copy(src_ref.at[window], buf, sem)
+                win = (pl.ds(coords[0], 1),) + win
+            return pltpu.make_async_copy(
+                src_ref.at[win],
+                buf.at[frame.region((0,) * ndim, frame.shape)], sem)
 
         @pl.when(lin == 0)
         def _prologue():
@@ -854,22 +1098,18 @@ def build_padded_pipelined_kernel(program: StencilProgram, plan: BlockPlan,
         def _prefetch_even():
             _copy(nxt, buf0, sem0).start()
 
-        coeffs = ProgramCoeffs(center=c_ref[0, 0], taps=t_ref[...][0])
+        center, taps = _coeff_scalars(c_ref, t_ref, ntaps, work.dtype)
+        starts = tuple(offs_ref[d] + pids[d] * block[d] - ring[d]
+                       for d in range(ndim))
+        win_out = hb + tuple(pl.ds(ring[d] + pids[d] * block[d], block[d])
+                             for d in range(ndim))
 
         def _compute(buf, sem):
             _copy(lin, buf, sem).wait()
-            cur = buf[...] if batch is None else buf[0]
-            starts0 = tuple(offs_ref[d] + pids[d] * block[d] - h
-                            for d in range(ndim))
-            cur = boundary_fixup(program, cur, starts0, global_shape)
-            res = _fused_steps(program, plan, coeffs, cur, pids, offs_ref,
-                               global_shape)
-            out_buf[...] = res if batch is None else res[jnp.newaxis]
-            win_out = tuple(pl.ds(H + pids[d] * block[d], block[d])
-                            for d in range(ndim))
-            if batch is not None:
-                win_out = (pl.ds(ids[0], 1),) + win_out
-            cpo = pltpu.make_async_copy(out_buf, o_ref.at[win_out], sem_out)
+            res = _fused_steps_vmem(program, plan.par_time, center, taps,
+                                    buf, work, frame, starts, global_shape)
+            cpo = pltpu.make_async_copy(res.at[frame.region(ring, block)],
+                                        o_ref.at[win_out], sem_out)
             cpo.start()
             cpo.wait()
 
@@ -883,16 +1123,16 @@ def build_padded_pipelined_kernel(program: StencilProgram, plan: BlockPlan,
 
     if wrap:
         def kernel(offs_ref, c_ref, t_ref, src_in, dst_in, src_ref, o_ref,
-                   buf0, buf1, out_buf, sem0, sem1, sem_out, sem_wrap):
+                   buf0, buf1, work, sem0, sem1, sem_out, sem_wrap):
             del src_in, dst_in
             _body(offs_ref, c_ref, t_ref, src_ref, o_ref, buf0, buf1,
-                  out_buf, sem0, sem1, sem_out, sem_wrap)
+                  work, sem0, sem1, sem_out, sem_wrap)
     else:
         def kernel(offs_ref, c_ref, t_ref, src_ref, dst_in, o_ref, buf0,
-                   buf1, out_buf, sem0, sem1, sem_out):
+                   buf1, work, sem0, sem1, sem_out):
             del dst_in
             _body(offs_ref, c_ref, t_ref, src_ref, o_ref, buf0, buf1,
-                  out_buf, sem0, sem1, sem_out, None)
+                  work, sem0, sem1, sem_out, None)
     return kernel
 
 
@@ -904,23 +1144,23 @@ def build_temporal_kernel(program: StencilProgram, plan: BlockPlan,
     """Superstep-chunk kernel: ``chunk`` supersteps fused into ONE launch.
 
     Overlapped tiling in time, lifted one level above the per-superstep
-    fusion: the launch DMAs a chunk-deep halo'd window
-    (``block + 2 * chunk * plan.halo`` per axis) out of the padded carry,
-    applies ``chunk * plan.par_time`` stencil applications with shrinking
-    valid regions — each inner step consumes ``halo_radius`` cells of the
-    overlap (paper eq. 2) — and writes only the final block interior back.
-    The carry ping-pong and the per-block window stream are thus paid once
-    per ``chunk`` supersteps, dropping per-superstep HBM traffic to ~1/chunk
-    of the plain kernel's (``BlockPlan.run_bytes_per_superstep`` with
+    fusion: the launch DMAs a chunk-deep frame (``block + 2 * chunk *
+    plan.halo`` per axis, tile-rounded when compiled) out of the padded
+    carry, applies ``chunk * plan.par_time`` stencil applications — each
+    inner step consumes ``halo_radius`` cells of the overlap (paper eq. 2)
+    — and writes only the final block interior back.  The carry ping-pong
+    and the per-block frame stream are thus paid once per ``chunk``
+    supersteps, dropping per-superstep HBM traffic to ~1/chunk of the plain
+    kernel's (``BlockPlan.run_bytes_per_superstep`` with
     ``variant="temporal"`` is the model; the traffic guard in
     tests/test_temporal_variant.py measures it).
 
     Structurally this IS :func:`build_padded_superstep_kernel` built for the
-    chunk-deep plan (``par_time * chunk``): the shrinking-region loop,
-    per-step boundary fixup, ring-offset window reuse, and wrap refresh are
-    all shared, so the temporal variant inherits the plain path's proven
-    boundary semantics — only the traffic accounting changes.  ``layout``
-    must carry the chunk-deep ring (``layout.halo >= chunk * plan.halo``).
+    chunk-deep plan (``par_time * chunk``): the step loop, per-step boundary
+    fixup, frame reuse, and wrap refresh are all shared, so the temporal
+    variant inherits the plain path's proven boundary semantics — only the
+    traffic accounting changes.  ``layout`` must carry the chunk-deep ring
+    (``layout.halo >= chunk * plan.halo``).
     """
     deep = dataclasses.replace(plan, par_time=plan.par_time * chunk)
     return build_padded_superstep_kernel(program, deep, layout, global_shape,
@@ -949,40 +1189,31 @@ def _padded_superstep_pallas(src: jnp.ndarray, dst: jnp.ndarray,
     source as a second output (its ring refresh mutates the buffer);
     clamp/constant leave ``src`` a plain input so the executable carries a
     single P-sized output.  ``variant`` supersedes the deprecated
-    ``pipelined`` bool (``None`` defers to it).
+    ``pipelined`` bool (``None`` defers to it).  The coefficients ride in
+    SMEM; the compiler's scoped-VMEM limit is the launch's frame buffers
+    plus :data:`VMEM_HEADROOM_BYTES`.
     """
     v = normalize_variant(variant, pipelined)
     ndim = program.ndim
     batch: Optional[int] = src.shape[0] \
         if batch_dims(program, src.ndim) else None
     block = plan.block_shape
-    # The temporal kernel streams the chunk-deep window of the chunk-deep
-    # plan; its output block (and hence the pallas grid) is unchanged.
-    eff_plan = plan if v != "temporal" else dataclasses.replace(
-        plan, par_time=plan.par_time * TEMPORAL_CHUNK)
     grid = tuple(layout.rounded[d] // block[d] for d in range(ndim))
     wrap = bool(layout.wrap_axes)
 
     if offsets is None:
         offsets = jnp.zeros((ndim,), jnp.int32)
-    c2 = center.reshape((1, 1)).astype(src.dtype)
-    t2 = taps.reshape((1, -1)).astype(src.dtype)
+    c1 = center.reshape((1,)).astype(jnp.float32)
+    t1 = taps.reshape((-1,)).astype(jnp.float32)
 
-    buf_shape = eff_plan.padded_shape if batch is None \
-        else (1,) + eff_plan.padded_shape
-    out_buf_shape = block if batch is None else (1,) + block
+    frame, _ = _launch_geometry(program, plan, layout, batch)
+    frame_buf = vmem_scratch(frame.buffer_shape(), src.dtype)
     if v == "pipelined":
         kernel = build_padded_pipelined_kernel(program, plan, layout,
                                                global_shape, grid,
                                                batch=batch)
-        scratch = [
-            vmem_scratch(buf_shape, src.dtype),
-            vmem_scratch(buf_shape, src.dtype),
-            vmem_scratch(out_buf_shape, src.dtype),
-            dma_semaphore,
-            dma_semaphore,
-            dma_semaphore,
-        ]
+        scratch = [frame_buf, frame_buf, frame_buf,
+                   dma_semaphore, dma_semaphore, dma_semaphore]
     else:
         if v == "temporal":
             kernel = build_temporal_kernel(program, plan, layout,
@@ -990,47 +1221,36 @@ def _padded_superstep_pallas(src: jnp.ndarray, dst: jnp.ndarray,
         else:
             kernel = build_padded_superstep_kernel(program, plan, layout,
                                                    global_shape, batch=batch)
-        scratch = [
-            vmem_scratch(buf_shape, src.dtype),
-            vmem_scratch(out_buf_shape, src.dtype),
-            dma_semaphore,
-            dma_semaphore,
-        ]
+        scratch = [frame_buf, frame_buf, dma_semaphore, dma_semaphore]
     if wrap:
         scratch.append(dma_semaphore)
+    frames = 3 if v == "pipelined" else 2
+    vmem_limit = frames * math.prod(frame.buffer_shape()) \
+        * jnp.dtype(src.dtype).itemsize + VMEM_HEADROOM_BYTES
 
     vgrid = grid if batch is None else (batch,) + grid
     in_specs = [
         pl.BlockSpec(memory_space=MemorySpace.SMEM),
-        pl.BlockSpec(c2.shape, lambda *g: (0,) * 2),
-        pl.BlockSpec(t2.shape, lambda *g: (0,) * 2),
+        pl.BlockSpec(memory_space=MemorySpace.SMEM),
+        pl.BlockSpec(memory_space=MemorySpace.SMEM),
         pl.BlockSpec(memory_space=MemorySpace.ANY),
         pl.BlockSpec(memory_space=MemorySpace.ANY),
     ]
     struct = jax.ShapeDtypeStruct(src.shape, src.dtype)
-    if wrap:
-        out = pl.pallas_call(
-            kernel,
-            grid=vgrid,
-            in_specs=in_specs,
-            out_specs=[pl.BlockSpec(memory_space=MemorySpace.ANY),
-                       pl.BlockSpec(memory_space=MemorySpace.ANY)],
-            out_shape=[struct, struct],
-            scratch_shapes=scratch,
-            input_output_aliases=dict(ping_pong_aliases(True)),
-            interpret=interpret,
-        )(offsets.astype(jnp.int32), c2, t2, src, dst)
-        return out[0], out[1]
+    any_spec = pl.BlockSpec(memory_space=MemorySpace.ANY)
     out = pl.pallas_call(
         kernel,
         grid=vgrid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=MemorySpace.ANY),
-        out_shape=struct,
+        out_specs=[any_spec, any_spec] if wrap else any_spec,
+        out_shape=[struct, struct] if wrap else struct,
         scratch_shapes=scratch,
-        input_output_aliases=dict(ping_pong_aliases(False)),
+        input_output_aliases=dict(ping_pong_aliases(wrap)),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
-    )(offsets.astype(jnp.int32), c2, t2, src, dst)
+    )(offsets.astype(jnp.int32), c1, t1, src, dst)
+    if wrap:
+        return out[0], out[1]
     return src, out
 
 
@@ -1137,7 +1357,9 @@ def run_call(grid: jnp.ndarray, center: jnp.ndarray,
                     for d in range(ndim))
     wrap_axes = tuple(range(ndim)) if program.boundary == "periodic" else ()
     layout = PaddedLayout(halo=H, local_shape=tuple(true_shape),
-                          rounded=rounded, wrap_axes=wrap_axes)
+                          rounded=rounded, wrap_axes=wrap_axes,
+                          align=tile_alignment(ndim, not interpret,
+                                               program.dtype))
     if layout.wrap_degenerate():
         fb_plan = plan if v != "temporal" else dataclasses.replace(
             plan, par_time=plan.par_time * TEMPORAL_CHUNK)
@@ -1147,9 +1369,9 @@ def run_call(grid: jnp.ndarray, center: jnp.ndarray,
                                      interpret=interpret, rem=rem,
                                      variant="plain" if v == "temporal"
                                      else v)
-    P = layout.padded_shape
+    P, ring = layout.padded_shape, layout.ring
     src = jnp.pad(grid, [(0, 0)] * nb + [
-        (H, P[d] - H - true_shape[d]) for d in range(ndim)])
+        (ring[d], P[d] - ring[d] - true_shape[d]) for d in range(ndim)])
     dst = jnp.zeros_like(src)
 
     def superstep(carry, step_plan, step_variant):
@@ -1160,13 +1382,23 @@ def run_call(grid: jnp.ndarray, center: jnp.ndarray,
             interpret=interpret, variant=step_variant)
         return (o, s2)
 
-    carry = lax.fori_loop(0, full, lambda _, c: superstep(c, plan, v),
-                          (src, dst))
-    if rem:
-        # The remainder (< chunk * par_time steps) runs as one plain (or
-        # pipelined) shallower superstep whose window reads at ring offset
-        # H - rem * halo_radius inside the same deep ring.
-        carry = superstep(carry, dataclasses.replace(plan, par_time=rem),
-                          "plain" if v == "temporal" else v)
-    return carry[0][(slice(None),) * nb + tuple(
-        slice(H, H + true_shape[d]) for d in range(ndim))]
+    interior = (slice(None),) * nb + tuple(
+        slice(ring[d], ring[d] + true_shape[d]) for d in range(ndim))
+
+    def finish(carry):
+        if rem:
+            # The remainder (< chunk * par_time steps) runs as one plain (or
+            # pipelined) shallower superstep: its steps depend only on the
+            # inner rem * halo_radius cells of the same deep ring.
+            carry = superstep(carry, dataclasses.replace(plan, par_time=rem),
+                              "plain" if v == "temporal" else v)
+        return carry[0][interior]
+
+    # Two supersteps per trip hand the ping-pong pair back to its own loop
+    # slots; a loop body that swaps them makes XLA copy both padded buffers
+    # on every trip.  An odd count runs its last full superstep in the tail.
+    carry = lax.fori_loop(
+        0, full // 2,
+        lambda _, c: superstep(superstep(c, plan, v), plan, v), (src, dst))
+    return lax.cond(full % 2 == 1,
+                    lambda c: finish(superstep(c, plan, v)), finish, carry)

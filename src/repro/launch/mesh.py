@@ -5,8 +5,8 @@ Multi-pod  : (pod=2, data=16, model=16)    = 512 chips
 
 A FUNCTION, not a module constant: importing this module must never touch
 jax device state (the dry-run sets XLA_FLAGS before first jax init).
-Mesh construction goes through ``repro.core.compat`` so the ``axis_types``
-kwarg drift across JAX versions is absorbed in one place.
+Mesh construction goes through ``repro.core.compat.make_mesh`` (every axis
+``Auto``).
 """
 
 from __future__ import annotations

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -44,10 +45,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.analysis.hw import TpuChip, V5E
+from repro.analysis.hw import TpuChip
 from repro.core.program import StencilProgram, as_program
 from repro.executor import (CompiledStencil, _normalize_variant_request,
                             stencil)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.tuning.cache import program_fingerprint
 
 
@@ -136,7 +138,7 @@ class StencilServer:
                  variant: Optional[str] = None,
                  use_autotune: bool = False,
                  cache_path: Optional[str] = None,
-                 hw: TpuChip = V5E,
+                 hw: Optional[TpuChip] = None,
                  max_par_time: int = 8,
                  mesh_devices: Optional[int] = None,
                  recorder: Optional["obs.Recorder"] = None):
@@ -402,6 +404,7 @@ def main(argv=None):
                          "(needs N visible devices, e.g. "
                          "XLA_FLAGS=--xla_force_host_platform_device_count=N)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     shape = tuple(int(p) for p in args.grid.split(",") if p)
     ndim = args.ndim or len(shape)
@@ -429,10 +432,14 @@ def main(argv=None):
     for key, why in server.mesh_fallbacks.items():
         print(f"[stencil-serve] mesh fallback {key[1]}: {why}")
     for rid in rids[:2]:
-        g = results[rid]
-        print(f"[stencil-serve] rid={rid} out_shape={g.shape} "
-              f"mean={float(g.mean()):+.5f}")
+        if rid in results:
+            g = results[rid]
+            print(f"[stencil-serve] rid={rid} out_shape={g.shape} "
+                  f"mean={float(g.mean()):+.5f}")
+    for rid, why in sorted(server.failed.items()):
+        print(f"[stencil-serve] rid={rid} FAILED: {why}")
+    return 1 if server.failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

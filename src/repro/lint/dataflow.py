@@ -50,7 +50,8 @@ STALE = -1
 
 def verify_dataflow(program, plan: BlockPlan, grid_shape, *,
                     steps: int, variant: Optional[str] = None,
-                    decomp=None, schedule=None) -> List[Diagnostic]:
+                    decomp=None, schedule=None,
+                    compiled: bool = False) -> List[Diagnostic]:
     """Prove the padded ring schedule of one run configuration correct.
 
     Returns every RP4xx finding (empty list == the schedule is sound).
@@ -60,6 +61,7 @@ def verify_dataflow(program, plan: BlockPlan, grid_shape, *,
     shard counts or a ``MeshDecomposition``; sharded exchange strips are
     modeled via SPMD symmetry (every shard sees the identical state
     pattern, so a neighbor's strip carries this shard's own timestamps).
+    ``compiled`` verifies the compiled kernels' tile-rounded ring.
     """
     from repro.kernels import common
 
@@ -67,7 +69,7 @@ def verify_dataflow(program, plan: BlockPlan, grid_shape, *,
     if schedule is None:
         schedule = common.ring_schedule(prog, plan, tuple(grid_shape),
                                         int(steps), variant=variant,
-                                        decomp=decomp)
+                                        decomp=decomp, compiled=compiled)
     if schedule.fallback or not schedule.supersteps:
         # The wrap-degenerate re-pad fallback re-materializes boundary_pad
         # every superstep — no ring schedule exists to verify (RP108
@@ -122,7 +124,7 @@ def _apply_copy(vec: np.ndarray, copy) -> None:
 
 def _verify_axis(sched, prog, plan: BlockPlan, d: int) -> List[Diagnostic]:
     layout = sched.layout
-    H = layout.halo
+    H = layout.ring[d]
     P = layout.padded_shape[d]
     n = layout.local_shape[d]
     R = layout.rounded[d]
@@ -167,7 +169,7 @@ def _verify_axis(sched, prog, plan: BlockPlan, d: int) -> List[Diagnostic]:
 
         # Window reads: block i reads [i*b + off, i*b + off + w); the
         # union over i is one contiguous interval (windows overlap).
-        off = ss.window_offset
+        off = ss.window_offset[d]
         w = ss.window_shape[d]
         lo = off
         hi = (nblocks - 1) * b + off + w
@@ -176,7 +178,7 @@ def _verify_axis(sched, prog, plan: BlockPlan, d: int) -> List[Diagnostic]:
                 "RP401",
                 f"superstep {ss.index}, axis {d}: block windows span "
                 f"[{lo}, {hi}) outside the padded buffer [0, {P})",
-                hint="window offset must be layout.halo - plan.halo and "
+                hint="window offset must be layout.ring - plan.halo and "
                      "the window block + 2*halo wide"))
         else:
             cells = np.arange(lo, hi)

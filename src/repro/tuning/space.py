@@ -48,7 +48,8 @@ from repro.backends.registry import (backend_traits, default_backend_name,
                                      get_backend, variant_of)
 from repro.core.blocking import (LANE, MIN_USEFUL_FRACTION, SUBLANE,
                                  TEMPORAL_CHUNK, VARIANTS, BlockPlan,
-                                 normalize_variant, round_up)
+                                 normalize_variant, round_up,
+                                 tile_alignment)
 from repro.core.program import as_program
 
 Shape = Tuple[int, ...]
@@ -187,10 +188,18 @@ class Candidate:
 
 # ---- pruning predicates (each maps one paper constraint) -------------------
 
-def eq2_csize(bsize: Shape, par_time: int,
-              halo_radius: int) -> Optional[Shape]:
-    """Paper eq. 2 per axis; None when any axis has csize <= 0."""
-    cs = tuple(b - 2 * par_time * halo_radius for b in bsize)
+def eq2_csize(bsize: Shape, par_time: int, halo_radius: int,
+              align: Optional[Shape] = None) -> Optional[Shape]:
+    """Paper eq. 2 per axis; None when any axis has csize <= 0.
+
+    ``align`` (:func:`repro.core.blocking.tile_alignment`) rounds the halo
+    up per axis, as a compiled kernel's carry ring is: an aligned window
+    then leaves an aligned csize, so every block the kernel DMAs starts on
+    a register tile.
+    """
+    align = align or (1,) * len(bsize)
+    cs = tuple(b - 2 * round_up(par_time * halo_radius, a)
+               for b, a in zip(bsize, align))
     return cs if all(c > 0 for c in cs) else None
 
 
@@ -203,7 +212,8 @@ def is_aligned(bsize: Shape) -> bool:
 
 def fits_vmem(plan: BlockPlan, chip: TpuChip,
               pipelined: bool = False,
-              variant: Optional[str] = None) -> bool:
+              variant: Optional[str] = None,
+              compiled: bool = True) -> bool:
     """Paper eq. 4/5 analogue: the kernel's VMEM scratch must fit the
     planner's budget (their DSP/BRAM caps, our on-chip SRAM cap).
 
@@ -212,10 +222,11 @@ def fits_vmem(plan: BlockPlan, chip: TpuChip,
     single window is ``TEMPORAL_CHUNK`` halo rings deeper — pruning plain
     plans with the double-buffered bound would forfeit bigger blocks /
     deeper par_time.  ``variant`` names the lowering; ``None`` defers to
-    the deprecated ``pipelined`` bool.
+    the deprecated ``pipelined`` bool.  ``compiled`` sizes the frames with
+    the tile-rounded ring of a compiled kernel.
     """
     v = normalize_variant(variant, pipelined)
-    return plan.vmem_bytes_for(v) <= chip.vmem_budget_bytes
+    return plan.vmem_bytes_for(v, compiled) <= chip.vmem_budget_bytes
 
 
 def halo_aligned(par_time: int, halo_radius: int) -> bool:
@@ -336,10 +347,15 @@ def enumerate_space(
             if n is not None)
 
     resolved = []
+    compiled = False
     for name in backends:
         version = get_backend(name, backend_version)[1]
-        resolved.append(
-            (name, version, backend_traits(name, version).variant))
+        traits = backend_traits(name, version)
+        resolved.append((name, version, traits.variant))
+        compiled |= traits.fused_run and not traits.interpret
+    # a compiled Pallas kernel DMAs tile-aligned blocks through a
+    # tile-rounded ring (csize per eq. 2 with the rounded halo)
+    align = tile_alignment(prog.ndim, compiled, prog.dtype)
 
     out: List[Candidate] = []
 
@@ -367,7 +383,7 @@ def enumerate_space(
                     plan = BlockPlan(spec=prog, block_shape=cs, par_time=pt)
                     if not fits_shard(plan, dc, grid_shape):
                         break   # halo grows with pt: no recovery
-                    if not fits_vmem(plan, chip):
+                    if not fits_vmem(plan, chip, compiled=compiled):
                         break   # window = csize + 2*halo grows with pt
                     if plan.useful_fraction <= min_useful_fraction:
                         break   # strictly decreasing in pt
@@ -380,7 +396,8 @@ def enumerate_space(
                             continue
                         # Variant-aware budget: the point may fit the plain
                         # kernel's single window but not the pipelined pair.
-                        if not fits_vmem(plan, chip, variant=var):
+                        if not fits_vmem(plan, chip, variant=var,
+                                         compiled=compiled):
                             continue
                         out.append(Candidate(plan=plan, backend=name,
                                              backend_version=version,
@@ -392,11 +409,11 @@ def enumerate_space(
         if len(bsize) != prog.ndim or not is_aligned(bsize):
             continue
         for pt in range(1, max_par_time + 1):
-            cs = eq2_csize(bsize, pt, r)
+            cs = eq2_csize(bsize, pt, r, align)
             if cs is None:
                 break                      # csize shrinks with pt: no recovery
             plan = BlockPlan(spec=prog, block_shape=cs, par_time=pt)
-            if not fits_vmem(plan, chip):
+            if not fits_vmem(plan, chip, compiled=compiled):
                 # The plain bound (window + shrinking output tile) decreases
                 # with pt, so deeper supersteps may still fit: keep probing.
                 continue
@@ -409,7 +426,7 @@ def enumerate_space(
             # *chunk-deep* overlap tax (eq. 2 with par_time*TEMPORAL_CHUNK
             # fused steps), so its redundancy floor is checked on the
             # deepened plan.
-            fits = {var: fits_vmem(plan, chip, variant=var)
+            fits = {var: fits_vmem(plan, chip, variant=var, compiled=compiled)
                     for _, _, var in resolved}
             if fits.get("temporal"):
                 deep = dataclasses.replace(

@@ -29,6 +29,9 @@ SHARDS = {
         "tests/test_program_ir.py",
         "tests/test_backends.py",
         "tests/test_properties.py",
+        # compiles the kernels for a described v5e (TPU compiler, no chip)
+        "tests/test_tpu_compile.py",
+        "tests/test_bringup.py",
     ],
     "models-tuning": [
         "tests/test_obs.py",

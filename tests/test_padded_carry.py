@@ -216,10 +216,11 @@ def _bytes_accessed(fn, g, k):
     return cost.get("bytes accessed")
 
 
-def test_per_superstep_traffic_within_model_bound():
+def test_per_superstep_traffic_within_model_bound(hbm_traffic_only):
     """The guard of ISSUE 6: marginal compiler-counted bytes of one
     superstep must stay within 1.2x of the run_bytes_per_superstep model
-    (kernel stream + 2x padded-carry pass-through).  The pre-change
+    (kernel stream + 2x padded-carry pass-through), counting HBM traffic
+    only (``hbm_traffic_only``).  The pre-change
     executor body exceeds that bound on the same probe — the guard has
     teeth — and the new path beats it by >= 1.5x (the acceptance
     criterion)."""

@@ -1,0 +1,115 @@
+"""The fused executor's kernels compile for a described TPU v5e.
+
+Nothing runs: each test lowers ``run_call`` (or the sharded ``run_fn`` on a
+2x2 mesh) with ``interpret=False`` at the paper's sizes and the plan the
+front door picks for the compiled backend, and lets the TPU compiler
+(Mosaic) accept or refuse it — unaligned DMA windows, scoped-VMEM limits
+and HBM overflow are refused here without a chip.  The topology is
+described inside a fixture (only the worker that runs this file loads the
+TPU library), and the tests skip where it cannot be described.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import repro
+from repro.analysis.hw import V5E
+from repro.configs import stencil2d, stencil3d
+from repro.core import compat
+from repro.core.distributed import Decomposition, DistributedStencil
+from repro.kernels import common
+from repro.tuning import autotune
+
+HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _assert_fits(compiled):
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the executable"
+    ma = compiled.memory_analysis()
+    used = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert used < HBM_BYTES, f"{used / 2**30:.1f} GiB exceeds the chip"
+
+
+def _compile_run_call(sharding, program, shape, variant):
+    cs = repro.stencil(program).compile(shape, steps=1, plan="auto",
+                                        cache=False, backend="pallas-tpu",
+                                        variant=variant)
+    assert cs.interpret is False
+    plan = cs.plan
+    args = (jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding),
+            jax.ShapeDtypeStruct((), jnp.float32, sharding=sharding),
+            jax.ShapeDtypeStruct((program.num_neighbor_taps,), jnp.float32,
+                                 sharding=sharding),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding))
+    compiled = common.run_call.lower(
+        *args, program=program, plan=plan, true_shape=shape,
+        interpret=False, rem=1, variant=variant).compile()
+    _assert_fits(compiled)
+    return plan
+
+
+@pytest.mark.parametrize("variant", ["plain", "pipelined", "temporal"])
+def test_2d_r4_paper_compiles(one_chip, variant):
+    w = stencil2d.workloads()["2d_r4_paper"]
+    _compile_run_call(one_chip, w.spec, w.grid_shape, variant)
+
+
+def test_3d_r4_paper_compiles(one_chip):
+    w = stencil3d.workloads()["3d_r4_paper"]
+    plan = _compile_run_call(one_chip, w.spec, w.grid_shape, "plain")
+    assert plan.block_shape[-1] % 128 == 0      # 704 lanes round to tiles
+
+
+def test_periodic_box_16384_compiles(one_chip):
+    box = stencil2d.workloads()["2d_box_periodic_pod"].spec
+    _compile_run_call(one_chip, box, (16384, 16384), "plain")
+
+
+def test_2x2_mesh_run_compiles(topo):
+    """The sharded fused run at one chip's share of the 2D pod config."""
+    program = stencil2d.workloads()["2d_r4_pod"].spec
+    shape = (8192, 8192)
+    tuned = autotune(program, V5E, grid_shape=shape, backend="pallas-tpu",
+                     n_devices=4, measure=False, cache=False)
+    shards = tuned.decomp
+    names = tuple(f"d{i}" for i in range(program.ndim))
+    mesh = compat.make_mesh(shards, names, devices=topo.devices)
+    decomp = Decomposition(tuple((names[i],) if shards[i] > 1 else ()
+                                 for i in range(program.ndim)))
+    dist = DistributedStencil(program, program.default_coeffs(), tuned.plan,
+                              mesh, decomp, shape, backend="pallas-tpu",
+                              _warn=False)
+    assert dist.interpret is False
+    rep = NamedSharding(mesh, P())
+    args = (jax.ShapeDtypeStruct(shape, jnp.float32,
+                                 sharding=dist.sharding(0)),
+            jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
+            jax.ShapeDtypeStruct((program.num_neighbor_taps,), jnp.float32,
+                                 sharding=rep),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=rep))
+    compiled = dist.run_fn(1, 0).lower(*args).compile()
+    _assert_fits(compiled)
+    assert np.prod(shards) == 4
+    assert "collective-permute" in compiled.as_text()
