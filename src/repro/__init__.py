@@ -16,10 +16,12 @@ pipelined runs through one executor (DESIGN.md §9).  The legacy entry
 points (``StencilEngine``, ``kernels.ops.stencil_run``,
 ``DistributedStencil``) survive as bit-compatible deprecation shims.
 
-``repro.obs`` is the flight recorder: ``with repro.obs.profile() as rec:``
-around any front-door work yields compile/run spans with achieved GB/s and
-the predicted-vs-measured model-accuracy ratio (``REPRO_OBS=1`` enables
-the same globally; off by default and free when off).
+``repro.obs`` is the flight recorder: every compile and run is a
+``repro.*`` span on the ``jax.profiler`` clock (never blocking, free when
+no profiler runs), JAX's compile phases inside them are summed in
+``repro.obs.compile_totals()``, and ``with repro.obs.profile() as rec:``
+(or ``REPRO_OBS=1``) records the spans' host times and the tuner's
+predicted-vs-measured accuracy samples (off by default).
 """
 
 from repro import obs

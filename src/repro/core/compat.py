@@ -5,24 +5,32 @@
 * :func:`shard_map` is ``jax.shard_map`` without the varying-manual-axes
   check (the stencil kernels are opaque Pallas calls).
 * :func:`tracing` tells host-side instrumentation that it runs inside a
-  jax trace.
+  jax trace; :func:`span` opens a program span only outside one.
 """
 
 from __future__ import annotations
 
 import jax
 
+from repro import obs
+
 
 def tracing(x) -> bool:
     """True when ``x``, the array argument of an instrumented call, is a
     jax tracer.
 
-    Host-side instrumentation (the ``repro.obs`` flight recorder, which is
-    deliberately jax-free) must not time, block, or emit per-run events
-    inside a trace — a jitted wrapper around an instrumented entry point
-    would otherwise record trace-time garbage once per compile.
+    Host-side instrumentation (the ``repro.obs`` spans) must not annotate,
+    time, or emit per-run events inside a trace — a jitted wrapper around
+    an instrumented entry point would otherwise record trace-time garbage
+    once per compile.
     """
     return isinstance(x, jax.core.Tracer)
+
+
+def span(name: str, x):
+    """The ``repro.<name>`` program span (``repro.obs.span``) around work
+    on ``x``, or the shared no-op where ``x`` is a tracer."""
+    return obs.NULL_SPAN if tracing(x) else obs.span(name)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):

@@ -52,7 +52,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import obs
 from repro.core import compat
 from repro.core.blocking import BlockPlan
 from repro.core.codegen import boundary_pad
@@ -414,7 +413,7 @@ class DistributedStencil:
             src = jnp.pad(grid, [(0, 0)] * nb + [(g, g) for g in ring])
             dst = jnp.zeros_like(src)
 
-            def superstep(carry, step_plan):
+            def superstep(carry, step_plan, role="superstep"):
                 s, d2 = carry
                 h = step_plan.halo
                 for dd in range(ndim):
@@ -426,7 +425,8 @@ class DistributedStencil:
                 s2, o = common._padded_superstep_pallas(
                     s, d2, center, taps, program=program, plan=step_plan,
                     layout=layout, global_shape=global_shape,
-                    interpret=interpret, offsets=offs, variant=variant)
+                    interpret=interpret, role=role, offsets=offs,
+                    variant=variant)
                 return (o, s2)
 
             interior = (slice(None),) * nb + tuple(
@@ -434,7 +434,7 @@ class DistributedStencil:
 
             def finish(carry):
                 if rem_plan is not None:
-                    carry = superstep(carry, rem_plan)
+                    carry = superstep(carry, rem_plan, role="remainder")
                 return carry[0][interior]
 
             # two supersteps per trip keep the pair in its loop slots (as
@@ -482,21 +482,7 @@ class DistributedStencil:
         if steps == 0:
             return grid
         full, rem = divmod(steps, self.plan.par_time)
-        rec = obs.active()
-        if rec is not None and not compat.tracing(grid):
-            # Tag what each superstep's ICI exchange moves: the full
-            # supersteps refresh a plan.halo-deep ring per sharded axis,
-            # the remainder superstep a shallower rem*halo_radius one.
-            rec.event(
-                "exchange",
-                depth=self.plan.halo,
-                rem_depth=rem * self.program.halo_radius,
-                supersteps=int(full), rem=rem,
-                decomp=[self.decomp.shards(self.mesh, d)
-                        for d in range(self.program.ndim)],
-                batch_rank=nb,
-                backend=f"{self.backend_name}@{self.backend_version}",
-                boundary=self.program.boundary)
         fn = self.run_fn(rem, nb)
-        return fn(grid, self.pcoeffs.center, self.pcoeffs.taps,
-                  jnp.asarray(full, jnp.int32))
+        with compat.span("launch", grid):
+            return fn(grid, self.pcoeffs.center, self.pcoeffs.taps,
+                      jnp.asarray(full, jnp.int32))

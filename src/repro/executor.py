@@ -53,8 +53,6 @@ from __future__ import annotations
 
 import math
 import operator
-import os
-import time
 import warnings
 from typing import Optional, Tuple, Union
 
@@ -67,7 +65,6 @@ from repro.backends import lower, resolve_backend
 from repro.core import compat
 from repro.core.blocking import BlockPlan, plan_blocking
 from repro.core.distributed import Decomposition, DistributedStencil
-from repro.core.perf_model import gbps_from_cells_per_s
 from repro.core.program import (ProgramCoeffs, StencilProgram, as_program,
                                 normalize_coeffs)
 from repro.kernels import common, ops
@@ -76,7 +73,6 @@ from repro.lint.diagnostics import error as _diag
 from repro.lint.dataflow import verify_dataflow
 from repro.lint.sanitize import sanitize_run
 from repro.lint.verify import check as _preflight
-from repro.tuning.cache import cache_key
 from repro.tuning.model_rank import RankedCandidate, predict, rank
 from repro.tuning.space import (Candidate, MeshDecomposition,
                                 enumerate_decompositions, fits_shard,
@@ -186,13 +182,12 @@ class Stencil:
                 sanitize: bool = False) -> "CompiledStencil":
         """Resolve plan, backend, and placement into a runnable executable.
 
-        See :meth:`_compile` for the parameter contract.  When the flight
-        recorder is on (``REPRO_OBS=1`` / ``repro.obs.profile()``) the whole
-        resolution is wrapped in a ``compile`` span carrying the plan
-        source, plan-cache hit/miss, backend@version, decomposition, the
-        model's HBM-traffic prediction, and — unless ``REPRO_OBS_COST=0`` —
-        the XLA ``cost_analysis`` bytes/FLOPs of the actual executable for
-        the model-vs-compiler traffic comparison.
+        See :meth:`_compile` for the parameter contract.  The whole
+        resolution is the ``repro.compile`` program span; when the flight
+        recorder is on (``REPRO_OBS=1`` / ``repro.obs.profile()``) the span
+        also records the plan source, plan-cache hit/miss, backend@version,
+        decomposition and the model's HBM-traffic prediction.  Nothing is
+        compiled here: XLA compiles at the first ``run``.
         """
         variant = _normalize_variant_request(variant, pipelined)
         kwargs = dict(steps=steps, batch=batch, devices=devices, plan=plan,
@@ -200,28 +195,19 @@ class Stencil:
                       interpret=interpret, hw=hw, max_par_time=max_par_time,
                       cache=cache, cache_path=cache_path, sanitize=sanitize)
         rec = obs.active()
-        if rec is None:
-            return self._compile(grid_shape, **kwargs)
         plan_source = plan if isinstance(plan, str) else "pinned"
-        before = common.trace_counts()
-        with rec.span("compile", plan_source=plan_source) as sp:
+        before = common.trace_counts() if rec is not None else None
+        with obs.span("compile", plan_source=plan_source) as sp:
             cs = self._compile(grid_shape, **kwargs)
-            supersteps = -(-cs.steps // cs.plan.par_time)
-            sp.set(**cs._span_attrs())
-            sp.set(cache_hit=cs.from_plan_cache,
-                   supersteps=supersteps,
-                   model_bytes_per_superstep=cs.plan.run_bytes_per_superstep(
-                       cs.grid_shape),
-                   trace_delta=_trace_delta(before) or None)
-            rec.count("compile.plan_cache_hit" if cs.from_plan_cache
-                      else "compile.plan_cache_miss")
-            if os.environ.get("REPRO_OBS_COST", "1") != "0":
-                cost = cs.xla_cost_analysis()
-                if cost:
-                    sp.set(**{f"xla_{k}": v for k, v in cost.items()})
-                    ba = cost.get("bytes_accessed")
-                    if ba:
-                        sp.set(xla_bytes_per_superstep=ba // supersteps)
+            if rec is not None:
+                sp.set(**cs._span_attrs())
+                sp.set(cache_hit=cs.from_plan_cache,
+                       supersteps=-(-cs.steps // cs.plan.par_time),
+                       model_bytes_per_superstep=cs.plan
+                       .run_bytes_per_superstep(cs.grid_shape),
+                       trace_delta=_trace_delta(before) or None)
+                rec.count("compile.plan_cache_hit" if cs.from_plan_cache
+                          else "compile.plan_cache_miss")
         return cs
 
     def _compile(self, grid_shape, *, steps: int,
@@ -638,91 +624,47 @@ class CompiledStencil:
 
         Any ``steps = k * par_time + rem`` with the remainder of an earlier
         call reuses that call's executable; only a new remainder (or batch
-        rank) compiles again.
+        rank) compiles again.  The call returns once its work is enqueued.
 
-        With the flight recorder on (``REPRO_OBS=1`` / an active
-        ``repro.obs.profile()``) each run emits a ``run`` span — wall time,
-        achieved MCell/s, effective GB/s, GFLOP/s, and the Table III-style
-        predicted-vs-measured accuracy ratio — plus an accuracy sample in
-        the history ledger; the recorded path blocks until the result is
-        ready (that is what a wall-time measurement means), while the
-        default path stays fully asynchronous.
+        Each call is the ``repro.run`` program span — validation, the
+        caller copy (``repro.copy``) and the executor's launch
+        (``repro.launch``) — on the profiler's clock; with the flight
+        recorder on it also records the host time of that dispatch.  No
+        span opens inside a JAX trace (a jitted wrapper around ``run``).
         """
-        steps = self.steps if steps is None else _check_steps(steps)
-        grid = jnp.asarray(grid)
-        self._check_grid(grid)
-        rec = obs.active()
-        # obs must not time or block under a jax trace: a jitted wrapper
-        # around run() would record trace-time garbage and block on tracers
-        if rec is None or compat.tracing(grid):
+        if compat.tracing(grid):
+            span = obs.NULL_SPAN
+        else:
+            rec = obs.active()
+            span = obs.span("run") if rec is None \
+                else rec.span("run", **self._span_attrs())
+        with span:
+            steps = self.steps if steps is None else _check_steps(steps)
+            grid = jnp.asarray(grid)
+            self._check_grid(grid)
             return self._dispatch(grid, steps)
-        return self._run_recorded(rec, grid, steps)
 
     def _dispatch(self, grid, steps: int):
         """Route one validated run to the matching internal executor."""
         if self._dist is not None:
             nb = 0 if self.batch is None else 1
-            g = jax.device_put(grid, self._dist.sharding(nb=nb))
-            if not self.donate and g is grid:
-                # device_put was a no-op (already committed with the target
-                # sharding): donation would consume the caller's buffer, so
-                # pay a copy; a fresh device_put result needs none
-                g = jnp.copy(g)
+            with compat.span("copy", grid):
+                g = jax.device_put(grid, self._dist.sharding(nb=nb))
+                if not self.donate and g is grid:
+                    # device_put was a no-op (already committed with the
+                    # target sharding): donation would consume the caller's
+                    # buffer, so pay a copy; a fresh device_put result
+                    # needs none
+                    g = jnp.copy(g)
             return self._dist.run(g, steps)
         if self._lowered is not None:
-            return self._lowered_jit(grid, steps)
+            with compat.span("launch", grid):
+                return self._lowered_jit(grid, steps)
         return ops._stencil_run(grid, self.program, self.coeffs, self.plan,
                                 steps, interpret=self.interpret,
                                 variant=self.variant)
 
-    def _run_recorded(self, rec, grid, steps: int):
-        """One dispatch under a ``run`` span + a history accuracy sample."""
-        before = common.trace_counts()
-        with rec.span("run", **self._span_attrs()) as sp:
-            t0 = time.perf_counter()
-            out = self._dispatch(grid, steps)
-            out = jax.block_until_ready(out)
-            dt = time.perf_counter() - t0
-            nb = 1 if self.batch is None else self.batch
-            cells_per_s = nb * math.prod(self.grid_shape) * steps / dt
-            gbps = gbps_from_cells_per_s(cells_per_s,
-                                         self.program.bytes_per_cell)
-            predicted = self.cost.predicted_gbps
-            accuracy = gbps / predicted if predicted else 0.0
-            sp.set(steps=steps, wall_s=dt,
-                   mcells_per_s=cells_per_s / 1e6,
-                   achieved_gbps=gbps,
-                   achieved_gflops=(cells_per_s
-                                    * self.program.flops_per_cell / 1e9),
-                   predicted_gbps=predicted,
-                   model_accuracy=accuracy,
-                   trace_delta=_trace_delta(before) or None)
-            rec.record_accuracy(
-                key=self.history_key(), chip=self.hw.name,
-                backend=self.backend, backend_version=self.backend_version,
-                grid_shape=list(self.grid_shape), batch=self.batch,
-                steps=steps, block_shape=list(self.plan.block_shape),
-                par_time=self.plan.par_time,
-                decomp=None if self.decomp is None else list(self.decomp),
-                predicted_gbps=predicted, achieved_gbps=gbps,
-                model_accuracy=accuracy,
-                mcells_per_s=cells_per_s / 1e6, source="executor.run")
-        return out
-
     # -- telemetry -----------------------------------------------------------
-
-    def history_key(self) -> str:
-        """The tuning cache key this executable's accuracy samples file
-        under — same addressing as the plan cache, so the calibration layer
-        joins samples to tuned plans directly.  Cached: fingerprinting the
-        program costs ~30us, too much for the per-run recording path."""
-        key = getattr(self, "_history_key", None)
-        if key is None:
-            key = cache_key(self.program, self.grid_shape, self.hw.name,
-                            self.backend, self.backend_version,
-                            decomp=self.decomp)
-            self._history_key = key
-        return key
 
     def _span_attrs(self) -> dict:
         return {
@@ -738,29 +680,3 @@ class CompiledStencil:
             "predicted_gbps": self.cost.predicted_gbps,
             "bound": self.cost.bound,
         }
-
-    def xla_cost_analysis(self) -> Optional[dict]:
-        """Best-effort XLA ``cost_analysis`` of this executable on abstract
-        inputs (no data, but a real compile — the flight recorder calls
-        this inside the ``compile`` span to compare the compiler's HBM
-        byte count against ``BlockPlan.run_bytes_per_superstep``).  Returns
-        None when the backend/platform does not expose the counters or the
-        dispatch path cannot be AOT-lowered (e.g. some mesh configurations).
-        """
-        try:
-            shape = self.grid_shape if self.batch is None \
-                else (self.batch,) + self.grid_shape
-            arg = jax.ShapeDtypeStruct(shape, jnp.dtype(self.program.dtype))
-            cost = jax.jit(lambda g: self._dispatch(g, self.steps)) \
-                .lower(arg).compile().cost_analysis()
-            if isinstance(cost, list):
-                cost = cost[0]
-            out = {}
-            for key, label in (("bytes accessed", "bytes_accessed"),
-                               ("flops", "flops")):
-                v = cost.get(key)
-                if v is not None:
-                    out[label] = int(v)
-            return out or None
-        except Exception:
-            return None

@@ -372,6 +372,7 @@ def _superstep_pallas(padded: jnp.ndarray, center: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct(out_shape, padded.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="stencil_superstep_unpadded",
     )(offsets.astype(jnp.int32), c2, t2, padded)
     return out
 
@@ -1173,12 +1174,17 @@ def _padded_superstep_pallas(src: jnp.ndarray, dst: jnp.ndarray,
                              layout: PaddedLayout,
                              global_shape: Tuple[int, ...],
                              interpret: bool,
+                             role: str = "superstep",
                              offsets: jnp.ndarray | None = None,
                              pipelined: bool = False,
                              variant: Optional[str] = None):
     """One superstep (or, for ``variant="temporal"``, one superstep-chunk
     advancing ``TEMPORAL_CHUNK`` supersteps) over the persistent padded
     carry.
+
+    ``role`` is the launch's place in the run, ``"superstep"`` (a full
+    superstep or chunk) or ``"remainder"``; the kernel is named
+    ``stencil_<role>_<variant>`` in the compiled program and its traces.
 
     ``src`` and ``dst`` are both in padded layout (``layout.padded_shape``
     per spatial axis, optionally behind one batch axis).  Returns
@@ -1248,6 +1254,7 @@ def _padded_superstep_pallas(src: jnp.ndarray, dst: jnp.ndarray,
         input_output_aliases=dict(ping_pong_aliases(wrap)),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
+        name=f"stencil_{role}_{v}",
     )(offsets.astype(jnp.int32), c1, t1, src, dst)
     if wrap:
         return out[0], out[1]
@@ -1374,12 +1381,12 @@ def run_call(grid: jnp.ndarray, center: jnp.ndarray,
         (ring[d], P[d] - ring[d] - true_shape[d]) for d in range(ndim)])
     dst = jnp.zeros_like(src)
 
-    def superstep(carry, step_plan, step_variant):
+    def superstep(carry, step_plan, step_variant, role="superstep"):
         s, d = carry
         s2, o = _padded_superstep_pallas(
             s, d, center, taps, program=program, plan=step_plan,
             layout=layout, global_shape=tuple(true_shape),
-            interpret=interpret, variant=step_variant)
+            interpret=interpret, role=role, variant=step_variant)
         return (o, s2)
 
     interior = (slice(None),) * nb + tuple(
@@ -1391,7 +1398,8 @@ def run_call(grid: jnp.ndarray, center: jnp.ndarray,
             # pipelined) shallower superstep: its steps depend only on the
             # inner rem * halo_radius cells of the same deep ring.
             carry = superstep(carry, dataclasses.replace(plan, par_time=rem),
-                              "plain" if v == "temporal" else v)
+                              "plain" if v == "temporal" else v,
+                              role="remainder")
         return carry[0][interior]
 
     # Two supersteps per trip hand the ping-pong pair back to its own loop

@@ -34,6 +34,7 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+from repro.core import compat
 from repro.core.blocking import BlockPlan, normalize_variant
 from repro.core.program import as_program, normalize_coeffs
 from repro.kernels import common
@@ -131,7 +132,10 @@ def _stencil_run(grid, spec, coeffs, plan: BlockPlan, steps: int, *,
     # The executor donates its first argument (the carry lives in padded
     # layout internally, pad-once-on-entry / slice-once-on-exit); copy so
     # the caller's buffer is never consumed.
-    return common.run_call(jnp.copy(grid), pc.center, pc.taps, full,
-                           program=program, plan=plan,
-                           true_shape=true_shape, interpret=interpret,
-                           rem=rem, variant=v)
+    with compat.span("copy", grid):
+        carry = jnp.copy(grid)
+    with compat.span("launch", grid):
+        return common.run_call(carry, pc.center, pc.taps, full,
+                               program=program, plan=plan,
+                               true_shape=true_shape, interpret=interpret,
+                               rem=rem, variant=v)

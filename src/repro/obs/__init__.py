@@ -1,19 +1,26 @@
-"""repro.obs — the flight recorder: structured tracing + run metrics.
+"""repro.obs — the flight recorder: program spans, compile phases, run metrics.
 
-The paper's headline claim is a performance *model* accurate to a few
-percent of measured throughput (Table III); this package turns that
-comparison from a once-per-bench artifact into continuously accumulated
-telemetry.  Every instrumented path — ``executor.compile``/``run``, the
-sharded exchange, the serving front, the tuner's measurement harness —
-emits structured events carrying predicted-vs-achieved GB/s, and accuracy
-samples append to a schema-versioned history ledger the calibration layer
-(ROADMAP item 3) can later fit from.
+Every instrumented path — ``executor.compile``/``run``, the caller copy
+and the executor's launch, the serving front's flush — opens a span.
+While a profiler records, a span is a ``jax.profiler.TraceAnnotation``
+named ``repro.<name>`` (``obs/profiler.py``), on the same clock as the
+device's ops; otherwise it only marks its thread as inside the program
+(about a microsecond).  Spans never block and never compile: a run
+span covers validation and what the call enqueued, not its completion.
+Spans open only outside a JAX trace.
 
-Off by default.  ``REPRO_OBS=1`` (or an active :func:`profile` scope)
-turns recording on; when off, every module-level helper short-circuits to
-a shared no-op — one dict lookup, no allocation — so instrumented hot
-paths cost nothing (the overhead guard in tests/test_obs.py bounds it at
-<2% of a fused smoke run).
+JAX's own compile phases inside those spans — tracing, lowering to MLIR
+(Pallas kernels built), backend compile or persistent-cache load — are
+summed process-wide and always on: :func:`compile_totals`.
+
+Recording — host-clock ``span`` events, counters, value streams, and
+predicted-vs-achieved accuracy samples (the tuner's measurement harness
+times candidates honestly and files one per candidate in a
+schema-versioned history ledger the calibration layer, ROADMAP item 3,
+can later fit from) — is off by default.  ``REPRO_OBS=1`` (or an active
+:func:`profile` scope) turns it on; when off, the counter and event
+helpers short-circuit after one dict lookup (the overhead guard in
+tests/test_obs.py bounds a span's cost at <2% of a fused smoke run).
 
 Usage::
 
@@ -22,8 +29,8 @@ Usage::
     with repro.obs.profile() as rec:
         cs = repro.stencil(program).compile((256, 1024), steps=8)
         out = cs.run(grid)
-    rec.spans("run")[0]["achieved_gbps"]     # measured effective bandwidth
-    rec.accuracy_samples()[0]["model_accuracy"]  # Table III-style ratio
+    rec.spans("run")[0]["dur_s"]       # host time of the call's dispatch
+    repro.obs.compile_totals()         # {"trace_s", "lower_s", ...}
 
 Env:
     REPRO_OBS          1/true enables the global recorder (default off)
@@ -46,7 +53,9 @@ from typing import Optional
 from repro.obs.history import (DEFAULT_HISTORY_PATH, SCHEMA_VERSION,
                                append_sample, default_history_path,
                                read_history)
-from repro.obs.recorder import NULL_SPAN, Recorder, Span, percentile
+from repro.obs import profiler
+from repro.obs.profiler import NULL_SPAN, Span, compile_totals
+from repro.obs.recorder import Recorder, percentile
 
 __all__ = [
     "NULL_SPAN",
@@ -55,6 +64,7 @@ __all__ = [
     "Span",
     "active",
     "append_sample",
+    "compile_totals",
     "count",
     "enabled",
     "event",
@@ -125,8 +135,10 @@ def disable() -> None:
 
 
 def reset() -> None:
-    """Forget the override, the env-driven recorder, and the cached
-    ``REPRO_OBS`` decision (test isolation / env re-reads)."""
+    """Forget the override, the env-driven recorder, the cached
+    ``REPRO_OBS`` decision and the compile totals (test isolation / env
+    re-reads)."""
+    profiler.reset()
     _state["override"] = None
     _state["env_off"] = None
     rec = _state["env_recorder"]
@@ -159,9 +171,13 @@ def profile(jsonl_path: Optional[str] = None,
 # -- module-level instrumentation helpers (no-ops when disabled) -------------
 
 def span(name: str, **attrs):
-    """A timed-region context manager, or the shared no-op when disabled."""
+    """The ``repro.<name>`` program span, recorded when recording is on
+    (:class:`Span`; the shared ``profiler.INSIDE`` when there is nothing
+    to record or annotate)."""
     rec = active()
-    return NULL_SPAN if rec is None else rec.span(name, **attrs)
+    if rec is None and not profiler.profiling():
+        return profiler.INSIDE
+    return Span(rec, name, attrs)
 
 
 def event(name: str, **attrs) -> None:

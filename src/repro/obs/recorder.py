@@ -1,11 +1,12 @@
 """Process-local flight recorder: spans, counters, value streams, JSONL sink.
 
-Zero-dependency by design (stdlib only, no jax import): the recorder must be
+Importable without jax (stdlib only at import): the recorder must be
 importable from every layer — kernels, executor, serving, benchmarks —
-without creating cycles or adding a cold-start cost, and it must keep
-working in subprocess test legs where jax is pinned to odd configurations.
+without creating cycles or adding a cold-start cost.  A span's annotation
+on the profiler's clock imports jax on first use (``obs/profiler.py``).
 
-A :class:`Recorder` is an append-only, thread-safe buffer of event dicts:
+A :class:`Recorder` is an append-only, thread-safe buffer of event dicts
+(its spans are ``obs/profiler.py``'s :class:`Span`):
 
     span     — a timed region (``{"type": "span", "name", "dur_s", ...}``)
     event    — a point-in-time fact (``{"type": "event", ...}``)
@@ -30,61 +31,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Sequence
 
-
-class _NullSpan:
-    """The disabled-path span: a shared, stateless, reusable no-op.
-
-    One module-level instance serves every disabled ``span()`` call, so the
-    off switch costs one attribute check and no allocation per site.
-    """
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set(self, **attrs) -> "_NullSpan":
-        return self
-
-
-NULL_SPAN = _NullSpan()
-
-
-class Span:
-    """A timed region; emits one ``span`` event when the context exits.
-
-    ``set(**attrs)`` attaches attributes mid-flight (metrics computed after
-    the timed work, e.g. achieved GB/s once the wall time is known).
-    """
-
-    __slots__ = ("_rec", "name", "attrs", "_t0", "dur_s")
-
-    def __init__(self, rec: "Recorder", name: str, attrs: dict):
-        self._rec = rec
-        self.name = name
-        self.attrs = attrs
-        self._t0 = None
-        self.dur_s = None
-
-    def set(self, **attrs) -> "Span":
-        self.attrs.update(attrs)
-        return self
-
-    def __enter__(self) -> "Span":
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> bool:
-        self.dur_s = time.perf_counter() - self._t0
-        ev = {"type": "span", "name": self.name, "dur_s": self.dur_s}
-        if exc_type is not None:
-            ev["error"] = exc_type.__name__
-        ev.update(self.attrs)
-        self._rec.emit(ev)
-        return False
+from repro.obs.profiler import Span
 
 
 class Recorder:

@@ -10,7 +10,7 @@ Renders the accumulated telemetry as a human summary:
   * every counter the recorded process flushed.
 
 ``--json`` emits the same structure machine-readably (CI asserts the smoke
-bench recorded accuracy samples per backend through it).
+autotune recorded accuracy samples for every bench backend through it).
 """
 
 from __future__ import annotations

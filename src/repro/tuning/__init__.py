@@ -333,7 +333,8 @@ def autotune(
     if measure:
         results = measure_frontier(prog, frontier, grid_shape,
                                    warmup=warmup, reps=reps,
-                                   supersteps=supersteps, seed=seed)
+                                   supersteps=supersteps, seed=seed,
+                                   chip=chip)
         measurement = best_measurement(results)
         if measurement is not None:
             winner = measurement.ranked

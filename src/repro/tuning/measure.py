@@ -16,6 +16,10 @@ hardware:
   model accuracy     — measured / model-estimated effective GB/s (the
                        paper's Table III "Model Accuracy" column)
 
+Each measured candidate files that ratio as an accuracy sample through
+``repro.obs.record_accuracy`` (a no-op unless the flight recorder is on),
+keyed by the tuning cache key, for the history ledger.
+
 A candidate that fails to lower, compile, or execute (Pallas rejects some
 shape/padding combinations; a backend may be unavailable off-TPU) yields a
 ``Measurement`` with ``ok=False`` carrying the error — the tuner skips it
@@ -36,6 +40,7 @@ from repro.analysis.hw import TpuChip, V5E
 from repro.core import reference as ref
 from repro.core.program import as_program
 from repro.backends import lower
+from repro.tuning.cache import cache_key
 from repro.tuning.model_rank import RankedCandidate, predict
 from repro.tuning.space import Candidate
 
@@ -92,10 +97,13 @@ def measure_candidate(
     reps: int = 2,
     supersteps: int = 2,
     seed: int = 0,
+    chip: TpuChip = V5E,
 ) -> Measurement:
     """Time ``supersteps`` fused supersteps of one candidate on a
     ``grid_shape`` grid; ``us_per_superstep`` is the steady-state
     per-superstep cost (dispatch overhead amortized over the fused run).
+    ``chip`` is the chip the candidate was ranked for (the accuracy
+    sample's key).
 
     ``warmup``/``reps``/``supersteps`` are honored exactly as given:
     ``warmup=0`` really skips warmup (the compile lands in the timed region
@@ -137,6 +145,17 @@ def measure_candidate(
     gcells = useful_cells / dt / 1e9
     gbps = gcells * prog.bytes_per_cell
     accuracy = gbps / ranked.predicted_gbps if ranked.predicted_gbps else 0.0
+    obs.record_accuracy(
+        key=cache_key(prog, grid_shape, chip.name, cand.backend,
+                      cand.backend_version),
+        chip=chip.name, backend=cand.backend,
+        backend_version=cand.backend_version, variant=cand.variant,
+        grid_shape=list(grid_shape), batch=None, steps=steps,
+        block_shape=list(cand.plan.block_shape),
+        par_time=cand.plan.par_time, decomp=None,
+        predicted_gbps=ranked.predicted_gbps, achieved_gbps=gbps,
+        model_accuracy=accuracy, mcells_per_s=gcells * 1e3,
+        source="tuning.measure")
     return Measurement(
         ranked=ranked,
         ok=True,
@@ -157,12 +176,13 @@ def measure_frontier(
     reps: int = 2,
     supersteps: int = 2,
     seed: int = 0,
+    chip: TpuChip = V5E,
 ) -> List[Measurement]:
     """Measure every frontier candidate; failures are kept (``ok=False``)
     so the caller can report *why* a model favourite did not survive."""
     return [measure_candidate(program, r, grid_shape,
                               warmup=warmup, reps=reps,
-                              supersteps=supersteps, seed=seed)
+                              supersteps=supersteps, seed=seed, chip=chip)
             for r in frontier]
 
 
@@ -176,7 +196,8 @@ def measure_candidates(
     """Convenience: predict + measure raw candidates (used by tests/CLI to
     sweep a whole small space rather than a ranked frontier)."""
     frontier = [predict(program, c, chip, grid_shape) for c in candidates]
-    return measure_frontier(program, frontier, grid_shape, **kwargs)
+    return measure_frontier(program, frontier, grid_shape, chip=chip,
+                            **kwargs)
 
 
 def best_measurement(

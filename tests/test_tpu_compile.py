@@ -9,6 +9,8 @@ described inside a fixture (only the worker that runs this file loads the
 TPU library), and the tests skip where it cannot be described.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -52,6 +54,14 @@ def _assert_fits(compiled):
     assert used < HBM_BYTES, f"{used / 2**30:.1f} GiB exceeds the chip"
 
 
+def _kernel_names(compiled):
+    """The instruction names of the Pallas kernels, without the ``.N``
+    the compiler appends: what a device trace calls them."""
+    return {re.sub(r"\.\d+$", "", m.group(1)) for m in re.finditer(
+        r"%([\w.-]+) = .*custom_call_target=\"tpu_custom_call\"",
+        compiled.as_text())}
+
+
 def _compile_run_call(sharding, program, shape, variant):
     cs = repro.stencil(program).compile(shape, steps=1, plan="auto",
                                         cache=False, backend="pallas-tpu",
@@ -67,6 +77,9 @@ def _compile_run_call(sharding, program, shape, variant):
         *args, program=program, plan=plan, true_shape=shape,
         interpret=False, rem=1, variant=variant).compile()
     _assert_fits(compiled)
+    rem_variant = "plain" if variant == "temporal" else variant
+    assert _kernel_names(compiled) == {f"stencil_superstep_{variant}",
+                                       f"stencil_remainder_{rem_variant}"}
     return plan
 
 
@@ -113,3 +126,5 @@ def test_2x2_mesh_run_compiles(topo):
     _assert_fits(compiled)
     assert np.prod(shards) == 4
     assert "collective-permute" in compiled.as_text()
+    assert _kernel_names(compiled) == {"stencil_superstep_plain",
+                                       "stencil_remainder_plain"}
