@@ -154,8 +154,10 @@ def _executor_rows(prog, shape, plan, rows):
                             variant="temporal")
         t_plain_t = _time(cs_pt.run, g, reps=2)
         t_temporal = _time(cs_t.run, g, reps=2)
-        mb_plain = plan.run_bytes_per_superstep(shape)
-        mb_temporal = plan.run_bytes_per_superstep(shape, "temporal")
+        compiled = not cs_t.interpret
+        mb_plain = plan.run_bytes_per_superstep(shape, compiled=compiled)
+        mb_temporal = plan.run_bytes_per_superstep(shape, "temporal",
+                                                   compiled)
         if plan.par_time >= 2:
             assert mb_temporal < mb_plain, \
                 (f"temporal modeled bytes/superstep {mb_temporal} not below "

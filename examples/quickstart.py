@@ -63,8 +63,9 @@ def main():
     outt = cst.run(grid)
     assert np.allclose(np.asarray(outt), np.asarray(out),
                        atol=1e-6, rtol=1e-5)
-    ratio = plan.run_bytes_per_superstep(grid_shape, "temporal") \
-        / plan.run_bytes_per_superstep(grid_shape)
+    compiled = not cst.interpret
+    ratio = plan.run_bytes_per_superstep(grid_shape, "temporal", compiled) \
+        / plan.run_bytes_per_superstep(grid_shape, compiled=compiled)
     print(f"variant={cst.variant}: matches plain at ulp; modeled HBM "
           f"bytes/superstep {ratio:.2f}x of plain  OK")
 

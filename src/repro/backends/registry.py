@@ -74,6 +74,13 @@ class BackendTraits:
     fused_run: bool = False
     variant: str = "plain"
 
+    @property
+    def compiled(self) -> bool:
+        """True when runs go through the compiled padded-carry kernel,
+        whose frames carry a tile-rounded ring (what the planner charges,
+        ``BlockPlan.cells_per_block``)."""
+        return self.fused_run and not self.interpret
+
     def __post_init__(self):
         # Keep the deprecated bool and the variant axis coherent no matter
         # which spelling a registration used.
@@ -270,7 +277,8 @@ def lower(program, plan: Optional[BlockPlan] = None, *,
     name = backend or default_backend_name()
     factory, v = get_backend(name, version)
     if plan is None and name != "xla-reference":
-        plan = plan_blocking(prog, grid_shape=grid_shape).plan
+        plan = plan_blocking(prog, grid_shape=grid_shape,
+                             compiled=backend_traits(name, v).compiled).plan
     lowered = factory(prog, plan, c)
     lowered.backend_name = name
     lowered.backend_version = v

@@ -167,31 +167,92 @@ class BlockPlan:
         return itemsize * frames * frame
 
     # ---- redundancy accounting (paper's overlapped blocking cost) ----------
+    #
+    # ``compiled`` selects the kernel being charged, as in vmem_bytes_for.
+    # A compiled padded-carry launch DMAs its whole frame (the block plus a
+    # tile-rounded ring) and sweeps all of it at every fused step: the
+    # kernel does not shrink its region.  Interpreter frames carry the
+    # exact ring, and their cost stays the exact-halo trapezoid of the
+    # paper's overlapped blocking (eq. 2), each step's region shrinking by
+    # ``halo_radius`` per side.
+
+    def ring(self, compiled: bool = True) -> Tuple[int, ...]:
+        """Per-axis ring a launch carries around its block: the halo,
+        rounded up to the register tile when ``compiled``
+        (:func:`tile_alignment`)."""
+        align = tile_alignment(self.program.ndim, compiled, self.spec.dtype)
+        return tuple(round_up(self.halo, a) for a in align)
+
+    def frame_shape(self, compiled: bool = True) -> Tuple[int, ...]:
+        """The window one launch DMAs in: the block plus :meth:`ring` on
+        both sides (the frame of ``kernels.common._launch_geometry``);
+        ``padded_shape`` when not ``compiled``."""
+        return tuple(b + 2 * g
+                     for b, g in zip(self.block_shape, self.ring(compiled)))
+
+    def cells_per_block(self, compiled: bool = True) -> int:
+        """Cell-updates one launch computes over its ``par_time`` steps.
+
+        Compiled: ``par_time`` sweeps of the frame — every row and lane,
+        and in 3D the planes ``halo_radius .. Z - halo_radius``
+        (``kernels.common._apply_step``).  Interpreter: the exact-halo
+        trapezoid, step t computing ``padded - 2 * (t + 1) * halo_radius``
+        per axis.
+        """
+        r = self.program.halo_radius
+        if compiled:
+            frame = list(self.frame_shape(compiled))
+            if len(frame) == 3:
+                frame[0] -= 2 * r
+            return self.par_time * math.prod(frame)
+        return sum(math.prod(p - 2 * (t + 1) * r for p in self.padded_shape)
+                   for t in range(self.par_time))
+
+    def useful_fraction_for(self, compiled: bool = True) -> float:
+        """Useful cell-updates over computed ones, per launch — the
+        overlapped-blocking tax (:data:`MIN_USEFUL_FRACTION` prunes on
+        it).  Compiled: the block over one sweep of the frame; otherwise
+        ``useful_fraction``."""
+        if not compiled:
+            return self.useful_fraction
+        return self.useful_cells_per_block() / self.cells_per_block(True)
 
     @property
     def useful_fraction(self) -> float:
-        """csize/bsize per axis, multiplied — the overlapped-blocking tax."""
+        """csize/bsize per axis, multiplied — the overlapped-blocking tax
+        of exact-halo windows."""
         frac = 1.0
         for b, p in zip(self.block_shape, self.padded_shape):
             frac *= b / p
         return frac
 
-    def hbm_bytes_per_block(self) -> int:
+    def hbm_bytes_per_block(self, compiled: bool = True) -> int:
+        """One read of the launch's frame plus one write of its block."""
         itemsize = 4 if self.spec.dtype == "float32" else 2
-        read = math.prod(self.padded_shape) * itemsize
+        read = math.prod(self.frame_shape(compiled)) * itemsize
         write = math.prod(self.block_shape) * itemsize
         return read + write
 
+    def blocks_per_superstep(self, grid_shape: Tuple[int, ...]) -> int:
+        """Launches one superstep makes over ``grid_shape``, rounded up to
+        whole blocks."""
+        return math.prod(round_up(g, b) // b
+                         for g, b in zip(grid_shape, self.block_shape))
+
     def run_bytes_per_superstep(self, grid_shape: Tuple[int, ...],
-                                variant: str = "plain") -> int:
+                                variant: str = "plain",
+                                compiled: bool = True) -> int:
         """HBM bytes one fused-run superstep moves for ``grid_shape``.
 
         The padded-carry executor's stream is the kernel's own traffic —
-        every block's overlapping halo'd read plus its tile write
-        (``hbm_bytes_per_block``) — plus one pass over each of the two
-        ping-pong padded buffers (the carry is read from one and written
-        through the other per superstep).  No O(volume) re-pad term: that
-        is precisely what the padded layout eliminated.
+        every block's overlapping frame read plus its tile write
+        (``hbm_bytes_per_block``).  A compiled launch moves nothing else:
+        it DMAs its frame out of one ping-pong buffer and its tile into
+        the other.  The interpreter's superstep also passes over each of
+        the two padded buffers once (the compiler's byte counter sees it;
+        tests/test_padded_carry.py calibrates this model against it).  No
+        O(volume) re-pad term: that is precisely what the padded layout
+        eliminated.
 
         ``variant="temporal"`` charges one chunk-deep launch (halo ring and
         window ``TEMPORAL_CHUNK`` times deeper) amortized over the
@@ -201,27 +262,33 @@ class BlockPlan:
         if normalize_variant(variant) == "temporal":
             deep = dataclasses.replace(
                 self, par_time=self.par_time * TEMPORAL_CHUNK)
-            return deep.run_bytes_per_superstep(grid_shape) // TEMPORAL_CHUNK
+            return deep.run_bytes_per_superstep(
+                grid_shape, compiled=compiled) // TEMPORAL_CHUNK
+        kernel = self.blocks_per_superstep(grid_shape) \
+            * self.hbm_bytes_per_block(compiled)
+        if compiled:
+            return kernel
         itemsize = 4 if self.spec.dtype == "float32" else 2
-        nblocks = math.prod(
-            round_up(g, b) // b
-            for g, b in zip(grid_shape, self.block_shape))
-        padded_carry = math.prod(
-            round_up(g, b) + 2 * self.halo
-            for g, b in zip(grid_shape, self.block_shape))
-        return nblocks * self.hbm_bytes_per_block() \
-            + 2 * padded_carry * itemsize
+        padded_carry = math.prod(round_up(g, b) + 2 * self.halo for g, b in
+                                 zip(grid_shape, self.block_shape))
+        return kernel + 2 * padded_carry * itemsize
 
-    def flops_per_block(self) -> int:
-        """Sum over the shrinking valid regions of each fused time step."""
-        prog = self.program
-        r = prog.halo_radius
-        total = 0
-        for t in range(self.par_time):
-            # region computed at step t has shape padded - 2*(t+1)*r
-            sizes = [p - 2 * (t + 1) * r for p in self.padded_shape]
-            total += math.prod(sizes) * prog.flops_per_cell
-        return total
+    def compute_redundancy(self, grid_shape: Tuple[int, ...],
+                           variant: str = "plain",
+                           compiled: bool = True) -> float:
+        """Cell-updates computed per superstep over the useful ones of
+        ``grid_shape`` (its round-up to whole blocks included): 1.0 is no
+        redundant work.  ``variant="temporal"`` charges the chunk-deep
+        launch."""
+        plan = self if normalize_variant(variant) != "temporal" else \
+            dataclasses.replace(self, par_time=self.par_time * TEMPORAL_CHUNK)
+        computed = plan.blocks_per_superstep(grid_shape) \
+            * plan.cells_per_block(compiled)
+        return computed / (math.prod(grid_shape) * plan.par_time)
+
+    def flops_per_block(self, compiled: bool = True) -> int:
+        """FLOPs of :meth:`cells_per_block`."""
+        return self.cells_per_block(compiled) * self.program.flops_per_cell
 
     def useful_cells_per_block(self) -> int:
         return math.prod(self.block_shape) * self.par_time
@@ -237,15 +304,17 @@ class PlanEstimate:
     bound: str                 # "compute" | "memory"
 
 
-def estimate(plan: BlockPlan, hw: TpuChip = V5E) -> PlanEstimate:
+def estimate(plan: BlockPlan, hw: TpuChip = V5E,
+             compiled: bool = True) -> PlanEstimate:
     """Single-chip throughput model = max(compute, HBM) per block round trip.
 
     Mirrors the paper's model role: predict useful throughput of a blocking
     configuration before committing to it (their place-and-route, our
-    lower/compile).
+    lower/compile).  ``compiled`` charges the compiled kernel's frame
+    (see ``BlockPlan.cells_per_block``); the interpreter's otherwise.
     """
-    t_compute = plan.flops_per_block() / hw.peak_vpu_f32_flops
-    t_hbm = plan.hbm_bytes_per_block() / hw.hbm_bytes_per_s
+    t_compute = plan.flops_per_block(compiled) / hw.peak_vpu_f32_flops
+    t_hbm = plan.hbm_bytes_per_block(compiled) / hw.hbm_bytes_per_s
     t = max(t_compute, t_hbm)
     useful = plan.useful_cells_per_block()
     gcells = useful / t
@@ -257,6 +326,22 @@ def estimate(plan: BlockPlan, hw: TpuChip = V5E) -> PlanEstimate:
         gflops_per_s=gcells * plan.spec.flops_per_cell,
         bound="compute" if t_compute >= t_hbm else "memory",
     )
+
+
+def model_order(rate: float, hbm_bytes_per_cell: float, halo_aligned: bool,
+                vmem_bytes: int) -> tuple:
+    """Sort key of a modelled plan, best first under ``reverse=True``.
+
+    The predicted rate comes first, to 12 significant digits: plans that
+    the model prices equally (compute-bound at one redundancy, as compiled
+    plans sharing a frame are) otherwise differ only by float rounding.
+    Ties go to the plan that moves fewer HBM bytes per useful cell-update
+    (a plain launch does not overlap its DMA with compute), then to a
+    sublane-aligned halo (the paper's eq. 6 trick), then to the smaller
+    VMEM footprint.
+    """
+    return (float(f"{rate:.12g}"), -hbm_bytes_per_cell, halo_aligned,
+            -vmem_bytes)
 
 
 def round_up(x: int, m: int) -> int:
@@ -283,6 +368,7 @@ def candidate_plans(
     block_candidates: Optional[Sequence[Tuple[int, ...]]] = None,
     pipelined: bool = False,
     variant: Optional[str] = None,
+    compiled: bool = True,
 ) -> list:
     """Enumerate alignment-respecting plans that fit the VMEM budget.
 
@@ -297,7 +383,9 @@ def candidate_plans(
     chunk-deep window shrinks it further still, so plain-kernel plans are
     pruned against the one-window bound (``BlockPlan.vmem_bytes_for``).
     Temporal plans are additionally pruned by the *chunk-deep* overlap tax —
-    the redundancy a temporal launch actually pays.
+    the redundancy a temporal launch actually pays.  ``compiled`` sizes
+    frames and the tax for the compiled kernel (tile-rounded ring) or the
+    interpreter (exact ring).
     """
     v = normalize_variant(variant, pipelined)
     if block_candidates is None:
@@ -314,11 +402,11 @@ def candidate_plans(
     for bs in block_candidates:
         for pt in range(1, max_par_time + 1):
             plan = BlockPlan(spec=spec, block_shape=tuple(bs), par_time=pt)
-            if plan.vmem_bytes_for(v) > hw.vmem_budget_bytes:
+            if plan.vmem_bytes_for(v, compiled) > hw.vmem_budget_bytes:
                 continue
             tax_plan = plan if v != "temporal" else dataclasses.replace(
                 plan, par_time=pt * TEMPORAL_CHUNK)
-            if tax_plan.useful_fraction <= MIN_USEFUL_FRACTION:
+            if tax_plan.useful_fraction_for(compiled) <= MIN_USEFUL_FRACTION:
                 continue  # overlapped-blocking tax beyond any win
             plans.append(plan)
     return plans
@@ -331,40 +419,42 @@ def plan_blocking(
     max_par_time: int = 64,
     pipelined: bool = False,
     variant: Optional[str] = None,
+    compiled: bool = True,
 ) -> PlanEstimate:
     """Pick the best plan by the model — the paper's §V.A tuning loop.
 
-    Preference order: highest predicted useful GCell/s; ties broken toward
-    aligned (par_time*radius) % SUBLANE == 0 and smaller VMEM.
+    Preference order: highest predicted useful GCell/s, ties broken by
+    :func:`model_order`.
 
     This is the *model-only, zero-dependency* planner behind
     ``backends.lower(plan=None)``; ``repro.tuning`` is its superset
     (bsize-space enumeration + empirical measurement + plan cache) and
     cannot be imported from here without a cycle through the backend
     registry.  Shared pieces (``MIN_USEFUL_FRACTION``, ``round_up``,
-    ``grid_useful_fraction``, the VMEM predicate on ``vmem_budget_bytes``)
-    live in this module so the two cannot drift.
+    ``grid_useful_fraction``, ``model_order``, the VMEM predicate on
+    ``vmem_budget_bytes``) live in this module so the two cannot drift.
+    ``compiled`` says which kernel runs the plan: the compiled one (the
+    default) or the interpreter, whose frames carry the exact ring.
     """
     v = normalize_variant(variant, pipelined)
     best = None
     for plan in candidate_plans(spec, hw, max_par_time=max_par_time,
-                                variant=v):
+                                variant=v, compiled=compiled):
         # A temporal launch streams the chunk-deep window and advances
         # TEMPORAL_CHUNK supersteps: estimate() on the chunk-deep plan IS
         # that launch's model, and its useful-GCell/s are directly
         # comparable to a plain superstep's.  The returned plan keeps the
         # caller-visible par_time.
-        if v == "temporal":
-            deep = dataclasses.replace(
-                plan, par_time=plan.par_time * TEMPORAL_CHUNK)
-            est = dataclasses.replace(estimate(deep, hw), plan=plan)
-        else:
-            est = estimate(plan, hw)
+        launch = plan if v != "temporal" else dataclasses.replace(
+            plan, par_time=plan.par_time * TEMPORAL_CHUNK)
+        est = dataclasses.replace(estimate(launch, hw, compiled), plan=plan)
         # blocks larger than the grid still work (the kernel pads), but
         # padded cells are wasted compute — penalize them.
         useful = grid_useful_fraction(grid_shape, plan.block_shape)
-        aligned = (plan.halo % SUBLANE) == 0
-        key = (est.gcells_per_s * useful, aligned, -plan.vmem_bytes)
+        key = model_order(est.gcells_per_s * useful,
+                          launch.hbm_bytes_per_block(compiled)
+                          / launch.useful_cells_per_block(),
+                          (plan.halo % SUBLANE) == 0, plan.vmem_bytes)
         if best is None or key > best[0]:
             best = (key, est)
     if best is None:

@@ -186,8 +186,9 @@ class Stencil:
         resolution is the ``repro.compile`` program span; when the flight
         recorder is on (``REPRO_OBS=1`` / ``repro.obs.profile()``) the span
         also records the plan source, plan-cache hit/miss, backend@version,
-        decomposition and the model's HBM-traffic prediction.  Nothing is
-        compiled here: XLA compiles at the first ``run``.
+        decomposition, the model's HBM-traffic prediction and the plan's
+        redundant work (``CompiledStencil.model_compute_redundancy``).
+        Nothing is compiled here: XLA compiles at the first ``run``.
         """
         variant = _normalize_variant_request(variant, pipelined)
         kwargs = dict(steps=steps, batch=batch, devices=devices, plan=plan,
@@ -204,7 +205,9 @@ class Stencil:
                 sp.set(cache_hit=cs.from_plan_cache,
                        supersteps=-(-cs.steps // cs.plan.par_time),
                        model_bytes_per_superstep=cs.plan
-                       .run_bytes_per_superstep(cs.grid_shape),
+                       .run_bytes_per_superstep(cs.grid_shape, cs.variant,
+                                                cs.cost.candidate.compiled),
+                       model_compute_redundancy=cs.model_compute_redundancy,
                        trace_delta=_trace_delta(before) or None)
                 rec.count("compile.plan_cache_hit" if cs.from_plan_cache
                           else "compile.plan_cache_miss")
@@ -371,7 +374,8 @@ class Stencil:
         elif plan == "model":
             resolved = plan_blocking(prog, hw, grid_shape=grid_shape,
                                      max_par_time=max_par_time,
-                                     variant=traits.variant).plan
+                                     variant=traits.variant,
+                                     compiled=traits.compiled).plan
             if n_devices > 1 and decomp_axes is None:
                 decomp_axes = _pick_decomposition(
                     prog, resolved, grid_shape, n_devices, hw, name, version)
@@ -570,6 +574,17 @@ class CompiledStencil:
                                         static_argnums=1)
         else:
             self._lowered_jit = lowered.run
+
+    @property
+    def model_compute_redundancy(self) -> float:
+        """Cell-updates the kernel computes per superstep over the useful
+        ones — the frames of every launch, the grid's round-up to whole
+        blocks included (``BlockPlan.compute_redundancy``); 1.0 is no
+        redundant work.  Charged like ``cost``, by the backend's kernel;
+        on a mesh the blocks tile every shard exactly, so the whole grid
+        gives the same ratio."""
+        return self.plan.compute_redundancy(
+            self.grid_shape, self.variant, self.cost.candidate.compiled)
 
     @property
     def from_plan_cache(self) -> bool:

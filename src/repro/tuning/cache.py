@@ -45,7 +45,11 @@ from repro.core.program import as_program
 #    ``variant`` request component, and ranking is variant-aware (the
 #    temporal chunk's amortized traffic/compute) — schema-3 records ranked
 #    temporal-free spaces under a variant-blind model and must miss.
-SCHEMA_VERSION = 4
+# 5: compiled plans are charged the frame the compiled kernel DMAs and
+#    sweeps at every fused step (tile-rounded ring, no shrinking region,
+#    no carry pass), and the overlap-tax prune uses that fraction —
+#    schema-4 winners were ranked by the exact-halo trapezoid and must miss.
+SCHEMA_VERSION = 5
 
 ENV_CACHE_PATH = "REPRO_TUNING_CACHE"
 _DEFAULT_PATH = os.path.join("~", ".cache", "repro-stencil", "plans.json")

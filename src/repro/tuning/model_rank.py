@@ -30,7 +30,7 @@ from typing import List, Optional, Sequence, Tuple
 from repro.analysis.hw import TpuChip, V5E
 from repro.core import perf_model
 from repro.core.blocking import (TEMPORAL_CHUNK, estimate,
-                                 grid_useful_fraction, round_up)
+                                 grid_useful_fraction, model_order)
 from repro.core.program import as_program
 from repro.tuning.space import Candidate
 
@@ -42,6 +42,7 @@ class RankedCandidate:
     predicted_gcells: float    # useful GCell/s (model)
     predicted_gflops: float    # useful GFLOP/s (model)
     bound: str                 # "compute" | "memory" | "ici"
+    hbm_bytes_per_cell: float = 0.0   # HBM bytes per useful cell-update
 
     def describe(self) -> str:
         return (f"{self.candidate.describe()} -> "
@@ -72,9 +73,13 @@ def predict(program, candidate: Candidate, chip: TpuChip = V5E,
     """Model prediction for one candidate (grid-padding waste charged when
     the target grid is known — same penalty ``blocking.plan_blocking``
     applies).  Decomposed candidates get the aggregate mesh model with the
-    exchange traffic charged (see module docstring)."""
+    exchange traffic charged (see module docstring).  Every branch charges
+    the frames of the candidate's backend (``Candidate.compiled``): a
+    compiled launch reads and sweeps its whole tile-rounded frame at every
+    fused step (``BlockPlan.cells_per_block``)."""
     prog = as_program(program)
     variant = candidate.variant
+    compiled = candidate.compiled
     if variant == "temporal":
         # One temporal launch streams the chunk-deep window and advances
         # TEMPORAL_CHUNK supersteps: the deepened plan's estimate IS that
@@ -83,24 +88,23 @@ def predict(program, candidate: Candidate, chip: TpuChip = V5E,
         deep = dataclasses.replace(
             candidate.plan,
             par_time=candidate.plan.par_time * TEMPORAL_CHUNK)
-        est = estimate(deep, chip)
+        est = estimate(deep, chip, compiled)
     else:
-        est = estimate(candidate.plan, chip)
+        est = estimate(candidate.plan, chip, compiled)
     decomp = candidate.decomp
     if decomp is not None and decomp.n_devices > 1:
         if grid_shape is None:
             raise ValueError(
                 "ranking a decomposed candidate needs grid_shape (exchange "
                 "traffic scales with the local extents)")
+        plan = candidate.plan
         local = decomp.local_shape(grid_shape)
-        itemsize = prog.bytes_per_cell // 2
-        blocks = math.prod(
-            -(-l // c) for l, c in zip(local, candidate.plan.block_shape))
-        # Kernel stream plus the executor's padded-carry pass-through: the
-        # sharded fused run reads one ping-pong buffer and writes the other
-        # per superstep (local extent + 2*halo ring per axis).
-        carry_s = 2 * math.prod(
-            l + 2 * candidate.plan.halo for l in local) * itemsize \
+        blocks = plan.blocks_per_superstep(local)
+        # Kernel stream plus, in the interpreter, the executor's
+        # padded-carry pass-through (``BlockPlan.run_bytes_per_superstep``
+        # on the local extent).
+        run_bytes = plan.run_bytes_per_superstep(local, variant, compiled)
+        carry_s = (run_bytes - blocks * plan.hbm_bytes_per_block(compiled)) \
             / chip.hbm_bytes_per_s
         t_local = blocks * max(est.compute_s_per_block,
                                est.hbm_s_per_block) + carry_s
@@ -119,6 +123,8 @@ def predict(program, candidate: Candidate, chip: TpuChip = V5E,
             predicted_gflops=useful * cells_per_s
             * prog.flops_per_cell / 1e9,
             bound="ici" if t_ici > t_local else est.bound,
+            hbm_bytes_per_cell=run_bytes
+            / (math.prod(local) * plan.par_time),
         )
     if grid_shape is not None:
         # Executor-traffic model: with the grid known, charge exactly what
@@ -129,17 +135,16 @@ def predict(program, candidate: Candidate, chip: TpuChip = V5E,
         # (round-up waste shows up as extra blocks, not a fraction), so the
         # grid_useful_fraction penalty is built in rather than multiplied.
         plan = candidate.plan
-        blocks = math.prod(
-            round_up(g, b) // b
-            for g, b in zip(grid_shape, plan.block_shape))
+        blocks = plan.blocks_per_superstep(grid_shape)
         # Temporal: est is the chunk-deep launch's model, so its per-block
         # compute amortizes over the TEMPORAL_CHUNK supersteps the launch
         # advances; run_bytes_per_superstep applies the same amortization
         # to the chunk's marginal HBM traffic.
         t_compute = blocks * est.compute_s_per_block \
             / (TEMPORAL_CHUNK if variant == "temporal" else 1)
-        t_mem = plan.run_bytes_per_superstep(grid_shape, variant) \
-            / chip.hbm_bytes_per_s
+        run_bytes = plan.run_bytes_per_superstep(grid_shape, variant,
+                                                 compiled)
+        t_mem = run_bytes / chip.hbm_bytes_per_s
         t_superstep = max(t_compute, t_mem)
         cells_per_s = math.prod(grid_shape) * plan.par_time / t_superstep
         return RankedCandidate(
@@ -149,6 +154,8 @@ def predict(program, candidate: Candidate, chip: TpuChip = V5E,
             predicted_gcells=cells_per_s / 1e9,
             predicted_gflops=cells_per_s * prog.flops_per_cell / 1e9,
             bound="compute" if t_compute >= t_mem else "memory",
+            hbm_bytes_per_cell=run_bytes
+            / (math.prod(grid_shape) * plan.par_time),
         )
     # == perf_model.predicted_gbps(prog, plan, chip) on the estimate above
     # (one shared formula, one estimate() evaluation per candidate).
@@ -160,6 +167,8 @@ def predict(program, candidate: Candidate, chip: TpuChip = V5E,
         predicted_gcells=est.gcells_per_s / 1e9,
         predicted_gflops=est.gflops_per_s / 1e9,
         bound=est.bound,
+        hbm_bytes_per_cell=est.hbm_s_per_block * chip.hbm_bytes_per_s
+        / est.plan.useful_cells_per_block(),
     )
 
 
@@ -169,12 +178,14 @@ def rank(program, candidates: Sequence[Candidate], chip: TpuChip = V5E,
          ) -> List[RankedCandidate]:
     """Rank candidates by predicted throughput, best first.
 
-    The returned list is non-increasing in ``predicted_gbps``; ``top_k``
-    truncates to the measurement frontier.
+    The returned list is non-increasing in ``predicted_gbps`` to 12
+    significant digits (``blocking.model_order`` orders the ties);
+    ``top_k`` truncates to the measurement frontier.
     """
     ranked = [predict(program, c, chip, grid_shape) for c in candidates]
-    ranked.sort(key=lambda r: (r.predicted_gbps,
-                               r.candidate.halo_aligned,
-                               -r.candidate.plan.vmem_bytes),
+    ranked.sort(key=lambda r: model_order(r.predicted_gbps,
+                                          r.hbm_bytes_per_cell,
+                                          r.candidate.halo_aligned,
+                                          r.candidate.plan.vmem_bytes),
                 reverse=True)
     return ranked if top_k is None else ranked[:top_k]

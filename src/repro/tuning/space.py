@@ -177,6 +177,11 @@ class Candidate:
     def par_time(self) -> int:
         return self.plan.par_time
 
+    @property
+    def compiled(self) -> bool:
+        """The backend runs the compiled kernel (tile-rounded frames)."""
+        return backend_traits(self.backend, self.backend_version).compiled
+
     def describe(self) -> str:
         mesh = "" if self.decomp is None \
             else f" mesh={self.decomp.describe()}"
@@ -303,7 +308,8 @@ def enumerate_space(
 
     Every returned candidate satisfies eq. 2 (positive csize on every axis),
     the bsize alignment predicate, and the VMEM budget; candidates whose
-    useful fraction (csize/bsize product) falls below
+    useful fraction (``BlockPlan.useful_fraction_for``: the block over the
+    frame the backend's kernel computes) falls below
     ``min_useful_fraction`` are pruned as unwinnable redundancy.
 
     ``n_devices`` (or explicit ``decompositions``) turns on the mesh
@@ -352,7 +358,7 @@ def enumerate_space(
         version = get_backend(name, backend_version)[1]
         traits = backend_traits(name, version)
         resolved.append((name, version, traits.variant))
-        compiled |= traits.fused_run and not traits.interpret
+        compiled |= traits.compiled
     # a compiled Pallas kernel DMAs tile-aligned blocks through a
     # tile-rounded ring (csize per eq. 2 with the rounded halo)
     align = tile_alignment(prog.ndim, compiled, prog.dtype)
@@ -385,8 +391,9 @@ def enumerate_space(
                         break   # halo grows with pt: no recovery
                     if not fits_vmem(plan, chip, compiled=compiled):
                         break   # window = csize + 2*halo grows with pt
-                    if plan.useful_fraction <= min_useful_fraction:
-                        break   # strictly decreasing in pt
+                    if plan.useful_fraction_for(compiled) \
+                            <= min_useful_fraction:
+                        break   # non-increasing in pt
                     for name, version, var in resolved:
                         # The temporal chunk advances TEMPORAL_CHUNK
                         # supersteps per launch but the mesh exchanges
@@ -417,8 +424,8 @@ def enumerate_space(
                 # The plain bound (window + shrinking output tile) decreases
                 # with pt, so deeper supersteps may still fit: keep probing.
                 continue
-            if plan.useful_fraction <= min_useful_fraction:
-                break   # strictly decreasing in pt; boundary matches
+            if plan.useful_fraction_for(compiled) <= min_useful_fraction:
+                break   # non-increasing in pt; boundary matches
                         # blocking.candidate_plans
             # Variant-aware budget: the point may fit the plain kernel's
             # single window but not the pipelined pair or the chunk-deep
@@ -431,7 +438,7 @@ def enumerate_space(
             if fits.get("temporal"):
                 deep = dataclasses.replace(
                     plan, par_time=pt * TEMPORAL_CHUNK)
-                if deep.useful_fraction <= min_useful_fraction:
+                if deep.useful_fraction_for(compiled) <= min_useful_fraction:
                     fits["temporal"] = False
             if decomps is not None:
                 # Mesh path, explicit windows: keep the caller's bsize

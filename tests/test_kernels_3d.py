@@ -34,7 +34,9 @@ def test_non_divisible_3d():
 
 
 def test_flops_accounting_3d():
-    """BlockPlan.flops_per_block sums the shrinking valid regions."""
+    """BlockPlan.flops_per_block sums the shrinking valid regions of the
+    interpreter's exact frames, and charges the compiled kernel par_time
+    sweeps of its tile-rounded frame (planes r .. Z - r)."""
     spec = StencilSpec(ndim=3, radius=1)
     plan = BlockPlan(spec=spec, block_shape=(8, 16, 128), par_time=2)
     pz, py, px = plan.padded_shape
@@ -42,4 +44,8 @@ def test_flops_accounting_3d():
     for t in range(1, 3):
         want += (pz - 2 * t) * (py - 2 * t) * (px - 2 * t) \
             * spec.flops_per_cell
-    assert plan.flops_per_block() == want
+    assert plan.flops_per_block(compiled=False) == want
+    # ring 2 rounds to (2, 8, 128): frame (12, 32, 384)
+    assert plan.frame_shape() == (12, 32, 384)
+    assert plan.flops_per_block() == 2 * (12 - 2) * 32 * 384 \
+        * spec.flops_per_cell

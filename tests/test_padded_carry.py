@@ -234,7 +234,7 @@ def test_per_superstep_traffic_within_model_bound(hbm_traffic_only):
     o2 = _bytes_accessed(_old_run_unrolled, g, 2)
     new_marginal = n2 - n1
     old_marginal = o2 - o1
-    model = _PROBE_PLAN.run_bytes_per_superstep(_PROBE_TRUE)
+    model = _PROBE_PLAN.run_bytes_per_superstep(_PROBE_TRUE, compiled=False)
     assert new_marginal <= 1.2 * model, (
         f"per-superstep bytes {new_marginal} exceed 1.2x model {model}: "
         f"an O(volume) copy crept back into the fused run")

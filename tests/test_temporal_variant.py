@@ -191,7 +191,7 @@ def test_temporal_marginal_traffic_guard(hbm_traffic_only):
     plain_meas = _marginal_bytes(cal_prog, cal_plan, cal_true, "plain")
     if plain_meas is None:
         pytest.skip("compiler does not expose bytes accessed")
-    plain_model = cal_plan.run_bytes_per_superstep(cal_true)
+    plain_model = cal_plan.run_bytes_per_superstep(cal_true, compiled=False)
     assert plain_meas <= 1.2 * plain_model, (
         f"plain model lost calibration: measured {plain_meas} vs model "
         f"{plain_model}")
@@ -201,7 +201,8 @@ def test_temporal_marginal_traffic_guard(hbm_traffic_only):
     cal_plan1 = BlockPlan(spec=cal_prog, block_shape=(128, 1024), par_time=1)
     temporal_meas = _marginal_bytes(cal_prog, cal_plan1, cal_true,
                                     "temporal")
-    temporal_model = cal_plan1.run_bytes_per_superstep(cal_true, "temporal")
+    temporal_model = cal_plan1.run_bytes_per_superstep(cal_true, "temporal",
+                                                       compiled=False)
     assert temporal_meas <= 1.2 * temporal_model, (
         f"temporal model lost calibration: measured {temporal_meas} vs "
         f"model {temporal_model}")
@@ -212,8 +213,9 @@ def test_temporal_marginal_traffic_guard(hbm_traffic_only):
     prog = StencilProgram(ndim=2, radius=2, boundary="clamp")
     plan = BlockPlan(spec=prog, block_shape=(16, 256), par_time=4)
     true = (37, 300)
-    mb_plain = plan.run_bytes_per_superstep(true)
-    mb_temporal = plan.run_bytes_per_superstep(true, "temporal")
+    mb_plain = plan.run_bytes_per_superstep(true, compiled=False)
+    mb_temporal = plan.run_bytes_per_superstep(true, "temporal",
+                                               compiled=False)
     assert mb_temporal <= 0.6 * mb_plain, (
         f"temporal marginal traffic {mb_temporal} not <= 0.6x plain "
         f"{mb_plain} at par_time=4 (ratio {mb_temporal / mb_plain:.3f})")
