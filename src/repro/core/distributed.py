@@ -354,6 +354,55 @@ class DistributedStencil:
 
         return stepf
 
+    def _shards(self) -> Tuple[int, ...]:
+        """Shards per grid axis."""
+        return tuple(self.decomp.shards(self.mesh, d)
+                     for d in range(self.program.ndim))
+
+    def _exchanged(self, d: int) -> bool:
+        """True when grid axis ``d`` is split over more than one shard."""
+        return bool(self.decomp.partition[d]) and self._shards()[d] > 1
+
+    def _layout(self) -> common.PaddedLayout:
+        """The padded carry of one shard in the fused run."""
+        ndim = self.program.ndim
+        shards = self._shards()
+        local = tuple(self.global_shape[d] // shards[d] for d in range(ndim))
+        # In-kernel wrap refresh covers device-local periodic axes only;
+        # sharded periodic axes wrap through the cyclic ppermute ring.
+        # __post_init__ guarantees local % block == 0 and halo <= local, so
+        # the layout is never wrap-degenerate here.
+        wrap_axes = tuple(
+            d for d in range(ndim)
+            if self.program.boundary == "periodic" and not self._exchanged(d))
+        return common.PaddedLayout(
+            halo=self.plan.halo, local_shape=local, rounded=local,
+            wrap_axes=wrap_axes,
+            align=common.tile_alignment(ndim, not self.interpret,
+                                        self.program.dtype))
+
+    def exchange_bytes_per_superstep(self, batch: Optional[int] = None
+                                     ) -> int:
+        """Bytes one shard sends over ICI in a full superstep of the fused
+        run: the operands of :func:`_exchange_into_ring`'s ppermutes, a
+        ``plan.halo``-deep strip each way along every split axis
+        (:func:`repro.kernels.common.exchange_copies`), over the padded
+        extents of the other axes and the ``batch`` grids.  A shard at a
+        clamp or constant edge sends one strip of the two on that axis;
+        this counts both, as an inner shard sends."""
+        layout = self._layout()
+        padded = layout.padded_shape
+        itemsize = jnp.dtype(self.program.dtype).itemsize * (batch or 1)
+        total = 0
+        for d in range(self.program.ndim):
+            if not self._exchanged(d):
+                continue
+            depth = sum(c.src[1] - c.src[0] for c in common.exchange_copies(
+                d, self.plan.halo, layout.ring[d], layout.local_shape[d]))
+            total += depth * itemsize * math.prod(
+                n for e, n in enumerate(padded) if e != d)
+        return total
+
     def run_fn(self, rem: int = 0, nb: int = 0):
         """The sharded fused run executor: ONE donated jitted executable
         ``(grid, center, taps, full) -> grid``.
@@ -380,23 +429,9 @@ class DistributedStencil:
         program, decomp, plan = self.program, self.decomp, self.plan
         ndim = program.ndim
         gspec = self._gspec(nb)
-        shards = tuple(decomp.shards(self.mesh, d) for d in range(ndim))
-        local = tuple(self.global_shape[d] // shards[d]
-                      for d in range(ndim))
-        H = plan.halo
+        shards, layout = self._shards(), self._layout()
+        local, ring = layout.local_shape, layout.ring
         periodic = program.boundary == "periodic"
-        # In-kernel wrap refresh covers device-local periodic axes only;
-        # sharded periodic axes wrap through the cyclic ppermute ring.
-        # __post_init__ guarantees local % block == 0 and halo <= local, so
-        # the layout is never wrap-degenerate here.
-        wrap_axes = tuple(
-            d for d in range(ndim)
-            if periodic and not (decomp.partition[d] and shards[d] > 1))
-        layout = common.PaddedLayout(
-            halo=H, local_shape=local, rounded=local, wrap_axes=wrap_axes,
-            align=common.tile_alignment(ndim, not self.interpret,
-                                        program.dtype))
-        ring = layout.ring
         interpret, variant = self.interpret, self.variant
         global_shape = tuple(self.global_shape)
         rem_plan = dataclasses.replace(plan, par_time=rem) if rem else None

@@ -187,7 +187,10 @@ class Stencil:
         recorder is on (``REPRO_OBS=1`` / ``repro.obs.profile()``) the span
         also records the plan source, plan-cache hit/miss, backend@version,
         decomposition, the model's HBM-traffic prediction and the plan's
-        redundant work (``CompiledStencil.model_compute_redundancy``).
+        redundant work (``CompiledStencil.model_compute_redundancy``), and
+        on a mesh the bytes one chip sends per exchange
+        (``CompiledStencil.exchange_bytes_per_superstep``) and the
+        exchanges a call makes, one a superstep.
         Nothing is compiled here: XLA compiles at the first ``run``.
         """
         variant = _normalize_variant_request(variant, pipelined)
@@ -201,14 +204,19 @@ class Stencil:
         with obs.span("compile", plan_source=plan_source) as sp:
             cs = self._compile(grid_shape, **kwargs)
             if rec is not None:
+                supersteps = -(-cs.steps // cs.plan.par_time)
                 sp.set(**cs._span_attrs())
                 sp.set(cache_hit=cs.from_plan_cache,
-                       supersteps=-(-cs.steps // cs.plan.par_time),
+                       supersteps=supersteps,
                        model_bytes_per_superstep=cs.plan
                        .run_bytes_per_superstep(cs.grid_shape, cs.variant,
                                                 cs.cost.candidate.compiled),
                        model_compute_redundancy=cs.model_compute_redundancy,
                        trace_delta=_trace_delta(before) or None)
+                if cs.exchange_bytes_per_superstep is not None:
+                    sp.set(exchange_bytes_per_superstep=cs
+                           .exchange_bytes_per_superstep,
+                           exchanges_per_call=supersteps)
                 rec.count("compile.plan_cache_hit" if cs.from_plan_cache
                           else "compile.plan_cache_miss")
         return cs
@@ -585,6 +593,15 @@ class CompiledStencil:
         gives the same ratio."""
         return self.plan.compute_redundancy(
             self.grid_shape, self.variant, self.cost.candidate.compiled)
+
+    @property
+    def exchange_bytes_per_superstep(self) -> Optional[int]:
+        """Bytes one chip sends over ICI per superstep of a mesh run (the
+        halo strips ``core/distributed._exchange_into_ring`` permutes,
+        counted from its padded geometry); None on one device."""
+        if self._dist is None:
+            return None
+        return self._dist.exchange_bytes_per_superstep(self.batch)
 
     @property
     def from_plan_cache(self) -> bool:

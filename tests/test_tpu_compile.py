@@ -24,6 +24,7 @@ from repro.analysis.hw import V5E
 from repro.configs import stencil2d, stencil3d
 from repro.core import compat
 from repro.core.distributed import Decomposition, DistributedStencil
+from repro.core.program import StencilProgram
 from repro.kernels import common
 from repro.tuning import autotune
 
@@ -100,13 +101,14 @@ def test_periodic_box_16384_compiles(one_chip):
     _compile_run_call(one_chip, box, (16384, 16384), "plain")
 
 
-def test_2x2_mesh_run_compiles(topo):
-    """The sharded fused run at one chip's share of the 2D pod config."""
-    program = stencil2d.workloads()["2d_r4_pod"].spec
-    shape = (8192, 8192)
+def _lower_mesh_run(topo, program, shape, steps):
+    """Lower the sharded fused run for ``steps`` steps a call on the
+    described 2x2 mesh, with the plan and split the mesh-aware tuner
+    picks."""
     tuned = autotune(program, V5E, grid_shape=shape, backend="pallas-tpu",
                      n_devices=4, measure=False, cache=False)
     shards = tuned.decomp
+    assert np.prod(shards) == 4
     names = tuple(f"d{i}" for i in range(program.ndim))
     mesh = compat.make_mesh(shards, names, devices=topo.devices)
     decomp = Decomposition(tuple((names[i],) if shards[i] > 1 else ()
@@ -122,9 +124,39 @@ def test_2x2_mesh_run_compiles(topo):
             jax.ShapeDtypeStruct((program.num_neighbor_taps,), jnp.float32,
                                  sharding=rep),
             jax.ShapeDtypeStruct((), jnp.int32, sharding=rep))
-    compiled = dist.run_fn(1, 0).lower(*args).compile()
+    rem = steps % tuned.plan.par_time
+    return dist, rem, dist.run_fn(rem, 0).lower(*args), args
+
+
+def test_2x2_mesh_run_compiles(topo):
+    """The sharded fused run at one chip's share of the 2D pod config."""
+    program = stencil2d.workloads()["2d_r4_pod"].spec
+    _, rem, lowered, _ = _lower_mesh_run(topo, program, (8192, 8192), 1)
+    assert rem == 1
+    compiled = lowered.compile()
     _assert_fits(compiled)
-    assert np.prod(shards) == 4
     assert "collective-permute" in compiled.as_text()
     assert _kernel_names(compiled) == {"stencil_superstep_plain",
                                        "stencil_remainder_plain"}
+
+
+def test_2x2_mesh_run_compiles_at_the_weak_scaled_paper_grid(topo):
+    """The ``star2d_r4_2x2`` deployment: Table III's 2D r4 grid on each
+    chip, 31360^2 in all, 64 steps a call.  Its exchange sends what
+    ``exchange_bytes_per_superstep`` counts from the tile-aligned ring."""
+    program = StencilProgram(ndim=2, radius=4, shape="star",
+                             boundary="clamp", dtype="float32")
+    dist, rem, lowered, args = _lower_mesh_run(topo, program,
+                                               (31360, 31360), 64)
+    # without a remainder: the loop body's two supersteps and the odd
+    # tail's one
+    full_only = lowered if rem == 0 else dist.run_fn(0, 0).lower(*args)
+    permutes = [4 * int(np.prod([int(n) for n in dims.split(",")]))
+                for dims in re.findall(
+                    r"= f32\[([\d,]+)\]\S* collective-permute\(",
+                    full_only.as_text(dialect="hlo"))]
+    assert sum(permutes) == 3 * dist.exchange_bytes_per_superstep()
+    compiled = lowered.compile()
+    _assert_fits(compiled)
+    assert "collective-permute" in compiled.as_text()
+    assert "stencil_superstep_plain" in _kernel_names(compiled)
