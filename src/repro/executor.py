@@ -187,8 +187,10 @@ class Stencil:
         recorder is on (``REPRO_OBS=1`` / ``repro.obs.profile()``) the span
         also records the plan source, plan-cache hit/miss, backend@version,
         decomposition, the model's HBM-traffic prediction and the plan's
-        redundant work (``CompiledStencil.model_compute_redundancy``), and
-        on a mesh the bytes one chip sends per exchange
+        redundant work (``CompiledStencil.model_compute_redundancy``), the
+        rows one strip of the kernel computes
+        (``CompiledStencil.kernel_strip_rows``), and on a mesh the bytes
+        one chip sends per exchange
         (``CompiledStencil.exchange_bytes_per_superstep``) and the
         exchanges a call makes, one a superstep.
         Nothing is compiled here: XLA compiles at the first ``run``.
@@ -212,6 +214,7 @@ class Stencil:
                        .run_bytes_per_superstep(cs.grid_shape, cs.variant,
                                                 cs.cost.candidate.compiled),
                        model_compute_redundancy=cs.model_compute_redundancy,
+                       kernel_strip_rows=cs.kernel_strip_rows,
                        trace_delta=_trace_delta(before) or None)
                 if cs.exchange_bytes_per_superstep is not None:
                     sp.set(exchange_bytes_per_superstep=cs
@@ -593,6 +596,26 @@ class CompiledStencil:
         gives the same ratio."""
         return self.plan.compute_redundancy(
             self.grid_shape, self.variant, self.cost.candidate.compiled)
+
+    @property
+    def kernel_strip_rows(self) -> Optional[int]:
+        """Rows one strip-loop iteration of the padded-carry kernel
+        computes: :func:`repro.kernels.common.strip_rows` of the frame
+        every launch of the run sweeps (block plus ring, the ring as
+        ``ring_schedule`` lays it out).  8, one row tile, is a frame too
+        wide for taller strips.  None when the run takes no such kernel
+        (a registry lowering, or the wrap-degenerate re-pad fallback)."""
+        if self._lowered is not None:
+            return None
+        sched = common.ring_schedule(
+            self.program, self.plan, self.grid_shape, self.steps,
+            variant=self.variant, decomp=self.decomp,
+            compiled=not self.interpret)
+        if sched.fallback:
+            return None
+        return common.strip_rows(tuple(
+            b + 2 * g
+            for b, g in zip(self.plan.block_shape, sched.layout.ring)))
 
     @property
     def exchange_bytes_per_superstep(self) -> Optional[int]:

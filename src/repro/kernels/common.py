@@ -47,7 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.blocking import (  # noqa: F401 (re-export)
-    SUBLANE, BlockPlan, TEMPORAL_CHUNK, guard_rows, normalize_variant,
+    LANE, SUBLANE, BlockPlan, TEMPORAL_CHUNK, guard_rows, normalize_variant,
     round_up, tile_alignment)
 from repro.core.codegen import boundary_pad, tap_interior_update
 from repro.core.program import ProgramCoeffs, StencilProgram
@@ -730,6 +730,32 @@ def _refresh_wrap_halo(src_ref, layout: PaddedLayout, batch: Optional[int],
 VMEM_HEADROOM_BYTES = 16 * 1024**2
 
 
+#: Output vregs (``SUBLANE`` x ``LANE`` tiles) one strip-loop iteration
+#: computes at most.  A narrow frame takes taller strips, so the loop's
+#: fixed cost per iteration and the multiplies repeated per strip are
+#: spread over more vregs; a wide frame (16 or more lane tiles) keeps one
+#: row tile a strip, past which the strip's values spill.
+STRIP_VREGS = 16
+
+
+def strip_rows(shape: Tuple[int, ...]) -> int:
+    """Rows one strip-loop iteration computes in a frame of ``shape``.
+
+    ``SUBLANE * k``, ``k`` the largest divisor of the frame's row tiles
+    with ``k`` times its lane tiles at most :data:`STRIP_VREGS` (at least
+    1), so the strips tile the rows exactly.  A frame whose rows are not
+    a whole number of row tiles (interpret mode's exact halo) keeps one
+    row tile a strip and ends with an overlapping strip.
+    """
+    rows, lanes = shape[-2], shape[-1]
+    if rows % SUBLANE:
+        return min(SUBLANE, rows)
+    tiles, cols = rows // SUBLANE, -(-lanes // LANE)
+    k = max(d for d in range(1, tiles + 1)
+            if tiles % d == 0 and (d == 1 or d * cols <= STRIP_VREGS))
+    return SUBLANE * k
+
+
 @dataclasses.dataclass(frozen=True)
 class _Frame:
     """One launch's VMEM working frame.
@@ -751,7 +777,7 @@ class _Frame:
 
     @property
     def strip(self) -> int:
-        return min(SUBLANE, self.shape[-2])
+        return strip_rows(self.shape)
 
     def buffer_shape(self) -> Tuple[int, ...]:
         s = list(self.shape)
@@ -776,10 +802,10 @@ def _for_each_strip(frame: _Frame, body, planes=None) -> None:
     """Call ``body(plane, r0)`` for every row strip of every plane.
 
     ``plane`` is ``()`` for 2D frames and ``(z,)`` for 3D ones, whose
-    leading axis loops over ``planes`` (default: all).  Compiled frames
-    have a strip-multiple row count, so every ``r0`` is a tile row; other
-    (interpret-mode) frames end with an overlapping strip, which recomputes
-    rows it already holds.
+    leading axis loops over ``planes`` (default: all).  A strip is
+    :func:`strip_rows` tall.  Compiled frames have a strip-multiple row
+    count, so every ``r0`` is a tile row; other (interpret-mode) frames end
+    with an overlapping strip, which recomputes rows it already holds.
     """
     rows = frame.shape[-2]
     S = frame.strip

@@ -140,8 +140,27 @@ def test_model_redundancy_counter_on_the_compiled_stencil():
     assert cs.model_compute_redundancy == pytest.approx(5.62, abs=0.01)
     (span,) = rec.spans("compile")
     assert span["model_compute_redundancy"] == cs.model_compute_redundancy
+    assert span["kernel_strip_rows"] == cs.kernel_strip_rows \
+        == common.strip_rows(plan.frame_shape())
     assert span["model_bytes_per_superstep"] \
         == plan.run_bytes_per_superstep(GRID_3D)
+
+
+@pytest.mark.parametrize("grid,backend,want", [
+    (GRID_2D, "pallas-tpu", 8),         # 2048-lane frame: one row tile
+    (GRID_3D, "pallas-tpu", 32),        # 512-lane frame: four row tiles
+    ((20, 40, 200), "xla-reference", None),     # no strip kernel
+])
+def test_kernel_strip_rows_counter_on_the_compile_span(grid, backend, want):
+    """``kernel_strip_rows`` is on the CompiledStencil and in the
+    ``repro.compile`` span; nothing runs."""
+    with obs.profile() as rec:
+        cs = repro.stencil(_star(len(grid))).compile(
+            grid, steps=20, backend=backend, cache=False)
+    (span,) = rec.spans("compile")
+    assert span["kernel_strip_rows"] == cs.kernel_strip_rows == want
+    if want is not None:
+        assert want == common.strip_rows(cs.plan.frame_shape())
 
 
 def test_compiled_3d_paper_plan_sweeps_at_most_3_3x():

@@ -96,6 +96,34 @@ def test_3d_r4_paper_compiles(one_chip):
     assert plan.block_shape[-1] % 128 == 0      # 704 lanes round to tiles
 
 
+@pytest.mark.parametrize("grid,steps,want", [
+    ((696, 728, 696), 20, 32),          # 512-lane frame: four row tiles
+    ((15680, 15680), 64, 8),            # 2048-lane frame: one row tile
+])
+def test_paper_plan_compiles_with_its_strip_rows(one_chip, grid, steps,
+                                                 want):
+    """Table III's r4 grids at the front door's plans: the 3D frame's
+    strip loop computes several row tiles an iteration and still compiles
+    within the launch's scoped VMEM (frames plus headroom); the 2D frame
+    keeps one row tile a strip."""
+    program = StencilProgram(ndim=len(grid), radius=4, shape="star",
+                             boundary="clamp", dtype="float32")
+    cs = repro.stencil(program).compile(grid, steps=steps, plan="auto",
+                                        cache=False, backend="pallas-tpu")
+    assert cs.kernel_strip_rows == want \
+        == common.strip_rows(cs.plan.frame_shape())
+    args = (jax.ShapeDtypeStruct(grid, jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((program.num_neighbor_taps,), jnp.float32,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    compiled = common.run_call.lower(
+        *args, program=program, plan=cs.plan, true_shape=grid,
+        interpret=False, rem=steps % cs.plan.par_time,
+        variant="plain").compile()
+    _assert_fits(compiled)
+
+
 def test_periodic_box_16384_compiles(one_chip):
     box = stencil2d.workloads()["2d_box_periodic_pod"].spec
     _compile_run_call(one_chip, box, (16384, 16384), "plain")
